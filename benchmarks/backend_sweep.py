@@ -83,7 +83,7 @@ def sweep_matrix(name: str, *, iters: int = 10, chip=None) -> dict:
         if entry.backend == "loop_reference" and m.nnz > LOOP_NNZ_CAP:
             backends[entry.backend] = {"skipped": f"nnz {m.nnz} > loop cap"}
             continue
-        fn = jax.jit(entry.build(obj, ctx).fn)
+        fn = entry.build(obj, ctx).jitted()
         t = _time_call(fn, x, iters)
         backends[entry.backend] = {
             "t_measured_s": t,
@@ -263,7 +263,7 @@ def tune_matrix(name: str, db, *, chip=None, top_k: int = 4,
                     .standard_normal(m.shape[1]).astype(dtype))
     cands, cand_times = [], {}
     for c in keep:
-        fn = jax.jit(c["entry"].build(c["obj"], ctx).fn)
+        fn = c["entry"].build(c["obj"], ctx).jitted()
         t = timer.measure(fn, (x,),
                           key=f"{name}/{c['tag']}/{c['entry'].backend}",
                           iters=iters)

@@ -4,74 +4,49 @@ The paper scales OpenMP threads across sockets; the TPU analogue scales
 chips.  With the distributed plan layer the figure becomes a *variant*
 comparison: ``allgather`` (shared input vector, the paper's baseline),
 ``ring`` (shard pipeline) and ``overlap`` (local compute concurrent with
-the first exchange, Schubert et al. 1106.5908) on 1..8 forced host devices
-(subprocess — device count must be fixed before jax init).  Per variant we
-report wall time, speedup vs its own 1-device time, and the modelled
-collective traffic.
+the first exchange, Schubert et al. 1106.5908) on 1..8 devices.  Per
+variant we report wall time, speedup vs its own 1-device time, and the
+modelled collective traffic.
+
+Everything runs in this process over meshes cut from ``jax.devices()``
+(one process per chip: a child process could not reach a chip this one
+holds).  Device counts beyond what the process has are skipped; on a CPU
+host, ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` provides 8.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import tempfile
+import time
 
 from .common import row
 
-_WORKER = r"""
-import os, sys, time, json
-os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={sys.argv[1]}"
-import jax, jax.numpy as jnp, numpy as np
-from repro.core.matrices import holstein_hubbard_surrogate
-from repro.core.distributed_plan import VARIANTS, compile_distributed_spmv_plan
-n = int(sys.argv[2])
-m = holstein_hubbard_surrogate(n, seed=0)
-parts = len(jax.devices())
-x = jax.random.normal(jax.random.PRNGKey(0), (n,), jnp.float32)
-out = {}
-for variant in VARIANTS:
-    plan = compile_distributed_spmv_plan(m, variant=variant)
-    jax.block_until_ready(plan(x))
-    best = 1e9
-    for _ in range(7):
-        t0 = time.perf_counter(); jax.block_until_ready(plan(x))
-        best = min(best, time.perf_counter() - t0)
-    out[variant] = {"t": best,
-                    "collective": plan.traffic["collective"],
-                    "x_copy": plan.traffic["per_chip_x"],
-                    "slab": plan.slab_format,
-                    "local_fraction": plan.local_fraction}
-print(json.dumps(out))
-"""
-
 
 def run(full: bool = False):
-    import json
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.distributed import make_mesh_1d
+    from repro.core.distributed_plan import VARIANTS, compile_distributed_spmv_plan
+    from repro.core.matrices import holstein_hubbard_surrogate
+
     n = 100_000 if full else 20_000
-    devs = [1, 2, 4, 8] if full else [1, 4]
-    rows = []
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with tempfile.NamedTemporaryFile("w", suffix=".py", delete=False) as f:
-        f.write(_WORKER)
-        worker = f.name
-    try:
-        base = {}
-        for d in devs:
-            env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
-            env.pop("XLA_FLAGS", None)
-            env.pop("REPRO_FORCE_DEVICES", None)
-            out = subprocess.run([sys.executable, worker, str(d), str(n)],
-                                 capture_output=True, text=True, env=env, timeout=600)
-            if out.returncode != 0:
-                rows.append(row("fig8", f"devices{d}", "ERROR", out.stderr[-120:]))
-                continue
-            res = json.loads(out.stdout.strip().splitlines()[-1])
-            for name, r in res.items():
-                if d == 1:
-                    base[name] = r["t"]
-                speedup = base.get(name, r["t"]) / r["t"]
-                rows.append(row("fig8", f"{name}_d{d}", r["t"] * 1e3, speedup,
-                                r["collective"] / 1e6, r["slab"]))
-    finally:
-        os.unlink(worker)
+    devs = [d for d in ([1, 2, 4, 8] if full else [1, 4])
+            if d <= len(jax.devices())]
+    m = holstein_hubbard_surrogate(n, seed=0)
+    x = jax.random.normal(jax.random.PRNGKey(0), (n,), jnp.float32)
+    rows, base = [], {}
+    for d in devs:
+        mesh = make_mesh_1d("data", d)
+        for variant in VARIANTS:
+            plan = compile_distributed_spmv_plan(m, mesh, variant=variant)
+            jax.block_until_ready(plan(x))
+            best = 1e9
+            for _ in range(7):
+                t0 = time.perf_counter()
+                jax.block_until_ready(plan(x))
+                best = min(best, time.perf_counter() - t0)
+            if d == devs[0]:
+                base[variant] = best
+            rows.append(row("fig8", f"{variant}_d{d}", best * 1e3,
+                            base.get(variant, best) / best,
+                            plan.traffic["collective"] / 1e6, plan.slab_format))
     return rows

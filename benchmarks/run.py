@@ -57,6 +57,8 @@ def main(argv=None) -> int:
                     help="write the plan/serving/corpus benchmarks as a JSON "
                          "artifact and exit; PATH defaults to BENCH_<tag>.json")
     args = ap.parse_args(argv)
+    from repro.utils import compile_cache
+    compile_cache.enable()
 
     if args.json is not None:
         from benchmarks.backend_sweep import run_json as backend_json

@@ -40,12 +40,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..utils.hw import ChipSpec, TPU_V5E
 from . import perfmodel as PM
@@ -322,39 +318,57 @@ def _slab_mult(pack: str, rows_pp: int, backend: str = "xla",
     return slab_mult(pack, rows_pp, backend, op=op)
 
 
-def _device_arrays(blocks: ShardSlabs) -> tuple:
-    """One device-put of the slab arrays, shared by the SpMV and SpMM
-    executors (and by every variant reusing the same packing).  ell ignores
-    row ids; a rank-3 dummy keeps the shard_map specs uniform."""
-    rid = (jnp.asarray(blocks.rid) if blocks.rid is not None
-           else jnp.zeros((blocks.parts, 1, 1), jnp.int32))
-    return (jnp.asarray(blocks.col), jnp.asarray(blocks.val), rid,
-            jnp.asarray(blocks.row_map))
+def _row_inverse(blocks: ShardSlabs) -> np.ndarray:
+    """Global row -> its (shard, local-row) slot in the flattened output.
+
+    Rows are partitioned, so each is produced by exactly one slot (pad
+    slots map to ``n_rows``): undoing the shard layout is an n-element
+    *gather*, not a scatter-add (which XLA:CPU lowers serially)."""
+    n = blocks.n_rows
+    rmap = np.asarray(blocks.row_map).reshape(-1)
+    pos = np.nonzero(rmap < n)[0]
+    if n and not (np.bincount(rmap[pos], minlength=n) == 1).all():
+        raise ValueError("row partition does not cover every row exactly once")
+    inv = np.empty(n, dtype=np.int32)
+    inv[rmap[pos]] = pos
+    return inv
+
+
+def _device_arrays(blocks: ShardSlabs, mesh: Mesh, axis: str) -> tuple:
+    """One device-put of the executor operands ``(col, val, rid, inv)``,
+    shared by the SpMV and SpMM executors (and by every variant reusing the
+    same packing).  The slabs are sharded over ``axis`` on their leading
+    (partition) dimension, so every device holds only its own; the row
+    inverse is replicated.  ell ignores row ids; a rank-3 dummy keeps the
+    shard_map specs uniform."""
+    rid = (blocks.rid if blocks.rid is not None
+           else np.zeros((blocks.parts, 1, 1), np.int32))
+    slabs = tuple(
+        jax.device_put(a, NamedSharding(mesh, P(axis, *([None] * (a.ndim - 1)))))
+        for a in (blocks.col, blocks.val, rid))
+    return (*slabs, jax.device_put(_row_inverse(blocks), NamedSharding(mesh, P())))
 
 
 def _make_executor(blocks: ShardSlabs, mesh: Mesh, axis: str, variant: str,
-                   multi: bool, arrays: tuple | None = None,
-                   backend: str = "xla"):
-    """Build the jitted distributed executor for one variant.
+                   multi: bool, backend: str = "xla"):
+    """Build the jitted distributed executor ``run(operands, x) -> y`` for
+    one variant (``x`` of shape (n,) or, with ``multi``, (n, K)).
 
-    Returns ``run(x) -> y`` (``multi=False``) or ``run(X) -> Y``.  All slabs
-    are device_put once (closed over as jnp constants); only x moves per
-    call.  ``backend`` picks the registry entry for the inner slab multiply
-    (``xla`` is the only entry expressible inside ``shard_map`` today;
-    ``loop_reference`` exists for parity testing).
+    ``operands`` are ``_device_arrays``: the sharded slabs enter the
+    program as arguments, never as embedded constants; only x moves per
+    call.  ``backend`` picks the registry entry for the inner slab
+    multiply (``xla`` is the only entry expressible inside ``shard_map``
+    today; ``loop_reference`` exists for parity testing).
     """
     parts = blocks.parts
     pack = blocks.pack
-    col, val, rid, rmap = arrays if arrays is not None else _device_arrays(blocks)
-    n, rows_pp = blocks.n_rows, blocks.rows_pp
+    rows_pp = blocks.rows_pp
     cs = blocks.col_shard
     mult = _slab_mult(pack, rows_pp, backend, op="spmm" if multi else "spmv")
     perm = [(j, (j - 1) % parts) for j in range(parts)]
 
-    def _mark_varying(y):
-        if hasattr(jax.lax, "pcast"):  # newer jax: accumulator must be varying
-            return jax.lax.pcast(y, (axis,), to="varying")
-        return y
+    def _mark_varying(y):  # the loop accumulator must be device-varying
+        return jax.lax.pcast(y, (axis,), to="varying")
 
     def _slab_at(colQ, valQ, ridQ, src):
         cb = jax.lax.dynamic_index_in_dim(colQ, src, 0, keepdims=False)
@@ -363,12 +377,12 @@ def _make_executor(blocks: ShardSlabs, mesh: Mesh, axis: str, variant: str,
         return cb, vb, rb
 
     if variant == "allgather":
-        def local(colb, valb, ridb, rmapb, xloc):
+        def local(colb, valb, ridb, xloc):
             xfull = jax.lax.all_gather(xloc, axis, tiled=True)
             y = mult(colb[0, 0], valb[0, 0], ridb[0, 0], xfull)
-            return y[None], rmapb
+            return y[None]
     elif variant == "ring":
-        def local(colb, valb, ridb, rmapb, xloc):
+        def local(colb, valb, ridb, xloc):
             colQ, valQ, ridQ = colb[0], valb[0], ridb[0]
             me = jax.lax.axis_index(axis)
 
@@ -385,9 +399,9 @@ def _make_executor(blocks: ShardSlabs, mesh: Mesh, axis: str, variant: str,
             y, xs = jax.lax.fori_loop(0, parts - 1, body, (y0, xloc))
             cb, vb, rb = _slab_at(colQ, valQ, ridQ, (me + parts - 1) % parts)
             y = y + mult(cb, vb, rb, xs)
-            return y[None], rmapb
+            return y[None]
     elif variant == "overlap":
-        def local(colb, valb, ridb, rmapb, xloc):
+        def local(colb, valb, ridb, xloc):
             colQ, valQ, ridQ = colb[0], valb[0], ridb[0]
             me = jax.lax.axis_index(axis)
 
@@ -407,49 +421,25 @@ def _make_executor(blocks: ShardSlabs, mesh: Mesh, axis: str, variant: str,
                 if s < parts - 1:
                     xs_next = jax.lax.ppermute(xs, axis, perm)
                 y = y + slab((me + s) % parts, xs)
-            return y[None], rmapb
+            return y[None]
     else:
         raise ValueError(f"unknown variant {variant!r}")
 
     slab_rank = 4 if pack == "ell" else 3
     spec_slab = P(axis, *([None] * (slab_rank - 1)))
-    spec_rid = P(axis, None, None)
-    spec_map = P(axis, None)
-    spec_x = P(axis, None) if multi else P(axis)
     f = _shard_map(
         local, mesh=mesh,
-        in_specs=(spec_slab, spec_slab, spec_rid, spec_map, spec_x),
-        out_specs=(spec_map if not multi else P(axis, None, None), spec_map),
+        in_specs=(spec_slab, spec_slab, P(axis, None, None),
+                  P(axis, None) if multi else P(axis)),
+        out_specs=P(axis, None, None) if multi else P(axis, None),
     )
 
-    # each global row is produced by exactly one (shard, local-row) slot
-    # (rows are partitioned; pad slots map to n), so undoing the shard
-    # layout is an inverse-map *gather* — not the scatter-add it used to
-    # be, which XLA:CPU lowers serially.  Guarded: any row mapped to zero
-    # or multiple slots falls back to the accumulating scatter.
-    rmap_h = np.asarray(rmap).reshape(-1)
-    pos = np.nonzero(rmap_h < n)[0]
-    counts = np.bincount(rmap_h[pos], minlength=n) if n else np.zeros(0, int)
-    if n == 0 or (counts == 1).all():
-        inv = np.empty(n, dtype=np.int32)
-        inv[rmap_h[pos]] = pos
-        inv = jnp.asarray(inv)
-
-        def run(x: jnp.ndarray) -> jnp.ndarray:
-            pad = parts * cs - x.shape[0]
-            xp = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-            yparts, _ = f(col, val, rid, rmap, xp)
-            tail = yparts.shape[2:]
-            return yparts.reshape((-1,) + tail)[inv]
-    else:  # pragma: no cover - no current pack duplicates a row slot
-        def run(x: jnp.ndarray) -> jnp.ndarray:
-            pad = parts * cs - x.shape[0]
-            xp = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-            yparts, rm = f(col, val, rid, rmap, xp)
-            tail = yparts.shape[2:]
-            out = jnp.zeros((n + 1,) + tail, dtype=yparts.dtype)
-            out = out.at[rm.reshape(-1)].add(yparts.reshape((-1,) + tail))
-            return out[:n]
+    def run(ops, x: jnp.ndarray) -> jnp.ndarray:
+        col, val, rid, inv = ops
+        pad = parts * cs - x.shape[0]
+        xp = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        yparts = f(col, val, rid, xp)
+        return yparts.reshape((-1,) + yparts.shape[2:])[inv]
 
     return jax.jit(run)
 
@@ -494,8 +484,9 @@ class DistributedSpMVPlan:
     balance: str                    # "nnz" | "rows"
     blocks: ShardSlabs
     shard_reports: tuple            # per-partition ShardReport
-    run: object                     # jitted f(x) -> y
-    run_mm: object                  # jitted f(X) -> Y
+    kernel: object                  # jitted f(operands, x) -> y
+    kernel_mm: object               # jitted f(operands, X) -> Y
+    operands: tuple                 # (col, val, rid, inv) on the mesh
     traffic: dict                   # modelled per-SpMV byte movement
     slab_backend: str = "xla"       # registry entry of the inner multiplies
 
@@ -520,7 +511,7 @@ class DistributedSpMVPlan:
         if x.shape != (self.blocks.n_cols,):
             raise ValueError(f"x has shape {x.shape}, expected ({self.blocks.n_cols},)")
         spec = faults.fire("dist.spmv", ctx=self._fault_ctx("spmv"))
-        y = self.run(x)
+        y = self.kernel(self.operands, x)
         return faults.poison(y, spec) if spec is not None else y
 
     def spmm(self, X: jnp.ndarray) -> jnp.ndarray:
@@ -533,7 +524,7 @@ class DistributedSpMVPlan:
         if X.ndim != 2 or X.shape[0] != self.blocks.n_cols:
             raise ValueError(f"X has shape {X.shape}, expected ({self.blocks.n_cols}, K)")
         spec = faults.fire("dist.spmm", ctx=self._fault_ctx("spmm"))
-        Y = self.run_mm(X)
+        Y = self.kernel_mm(self.operands, X)
         return faults.poison(Y, spec) if spec is not None else Y
 
     # -- back-compat + introspection ----------------------------------------
@@ -715,21 +706,23 @@ def _compile(m, mesh, variant, balance, slab_format, axis, C, chip, am,
     cache = getattr(m, "_dist_plans")
     local_cols = variant != "allgather"
     skey = ("slabs", balance, pack, local_cols, C, parts)
-    hit = cache.get(skey)
-    if hit is None:
-        blocks = pack_shard_slabs(m, parts, balance=balance, pack=pack,
-                                  local_cols=local_cols, C=C, bounds=bounds)
-        hit = (blocks, _device_arrays(blocks))
-        cache[skey] = hit
-    blocks, arrays = hit
+    blocks = cache.get(skey)
+    if blocks is None:
+        blocks = cache[skey] = pack_shard_slabs(
+            m, parts, balance=balance, pack=pack, local_cols=local_cols, C=C,
+            bounds=bounds)
+    dkey = (skey, axis, tuple(int(d.id) for d in np.asarray(mesh.devices).flat))
+    arrays = cache.get(dkey)
+    if arrays is None:
+        arrays = cache[dkey] = _device_arrays(blocks, mesh, axis)
     run = _make_executor(blocks, mesh, axis, variant, multi=False,
-                         arrays=arrays, backend=backend)
+                         backend=backend)
     run_mm = _make_executor(blocks, mesh, axis, variant, multi=True,
-                            arrays=arrays, backend=backend)
+                            backend=backend)
     traffic = slab_traffic_bytes(blocks, variant,
                                  np.dtype(np.asarray(m.val).dtype).itemsize)
     return DistributedSpMVPlan(variant, parts, axis, pack, balance, blocks,
-                               tuple(reports), run, run_mm, traffic,
+                               tuple(reports), run, run_mm, arrays, traffic,
                                slab_backend=backend)
 
 

@@ -854,10 +854,8 @@ def split_dia(m: CSR, min_occupancy: float = 0.5, max_diags: int = 16,
     offsets = np.asarray(sorted(chosen_set), dtype=np.int32)
     data = np.zeros((len(offsets), n), dtype=vals.dtype)
     if len(offsets):
-        off_pos = {o: k for k, o in enumerate(offsets.tolist())}
-        sel = np.nonzero(in_dia)[0]
-        for idx in sel:
-            data[off_pos[int(offs[idx])], rows[idx]] += vals[idx]
+        np.add.at(data, (np.searchsorted(offsets, offs[in_dia]), rows[in_dia]),
+                  vals[in_dia])
     dia = DIA(offsets, data, m.shape)
     # remainder
     rsel = ~in_dia

@@ -300,11 +300,16 @@ SELL_FLAT_OVERHEAD = {"cpu": 4.5, "tpu": 1.0}
 def sell_flat_overhead(family: str | None = None) -> float:
     """Flat-formulation execution-overhead factor for ``family``; ``None``
     resolves the family the kernels will actually execute on (the runtime
-    platform, not a modeled chip)."""
+    platform, not a modeled chip): ``cpu``, or ``tpu`` for a TPU kind in
+    ``utils.hw.DEVICE_KINDS`` — any other device is an error."""
     if family is None:
         import jax
 
-        family = "cpu" if jax.default_backend() == "cpu" else "tpu"
+        if jax.default_backend() == "cpu":
+            family = "cpu"
+        else:
+            from ..utils.hw import chip_for_device
+            family = chip_family(chip_for_device())
     return float(SELL_FLAT_OVERHEAD.get(family, 1.0))
 
 
@@ -628,12 +633,11 @@ def predict_exec(fmt: str, balance: float, nnz: int, chip: ChipSpec = TPU_V5E,
 
 
 def resolve_stream_backend(backend: str = "auto") -> str:
-    """The stream-byte regime the default executor would use here: the
-    Pallas kernels on TPU, the XLA formulations elsewhere."""
-    if backend != "auto":
-        return backend
-    import jax
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    """The stream-byte regime the default executor would use: the XLA
+    formulations — on TPU too, where the SELL Pallas kernels (the only
+    format whose streamed bytes differ per backend) do not lower and the
+    registry refuses them."""
+    return "xla" if backend == "auto" else backend
 
 
 def select_format(
@@ -903,21 +907,21 @@ def select_pallas_blocks(
     value_bytes: int = 4,
     index_bytes: int = 4,
     chip: ChipSpec = TPU_V5E,
-    vmem_fraction: float = 0.5,
     max_chunk_block: int = 64,
 ) -> BlockChoice:
     """Pick (chunk_block, width_block) for ``sell_spmv_arrays`` from the
     byte model alone: maximize the streamed slab (pipeline amortization)
-    subject to the VMEM working set fitting ``vmem_fraction`` of the chip's
-    VMEM (the rest is the double-buffering margin).  Prefers a full-width
-    block (one pass over the output tile, no revisits) when it fits.
+    subject to the VMEM working set fitting the kernels' budget
+    (``utils.hw.vmem_fits``).  Prefers a full-width block (one pass over
+    the output tile, no revisits) when it fits.
 
     Deterministic and host-only — the "autotuning" is the paper's predictive
     model applied to the kernel's BlockSpec instead of an on-device sweep.
     """
     from ..kernels.sell_spmv import vmem_bytes as _vmem_claim  # deferred: no cycle
 
-    budget = int(chip.vmem_bytes * vmem_fraction)
+    from ..utils.hw import vmem_fits
+
     width = max(1, width)
     n_chunks = max(1, n_chunks)
     # width_block candidates: powers of two up to width (padding W up to a
@@ -935,7 +939,7 @@ def select_pallas_blocks(
         w_pad = -(-width // wb) * wb
         for cb in _divisors_desc(n_chunks, max_chunk_block):
             claim = _vmem_claim(cb, wb, C, n_cols, value_bytes, index_bytes, value_bytes)
-            if claim > budget:
+            if not vmem_fits(claim, chip):
                 continue
             cand = BlockChoice(cb, wb, w_pad, int(claim), True)
             if best is None or (cand.chunk_block * cand.width_block

@@ -26,6 +26,9 @@ format container into a reusable executor:
 4. **Cached jitted executors** — ``plan(x)`` (SpMV) and ``plan.spmm(X)``
    (multi-vector) are jitted once; plans themselves are memoized on the
    container, so ``compile`` is idempotent and free after the first call.
+   The matrix is put on the device once and passed to the jitted
+   executors as *arguments*: a program never embeds it as constants, so
+   its size (and its compile time) does not grow with nnz.
 
 ``chip`` parameterizes the roofline (prediction + VMEM budget); ``backend``
 is ``"auto" | "xla" | "pallas" | "pallas_interpret" | "loop_reference"``
@@ -73,15 +76,26 @@ class PlanReport:
 class SpMVPlan:
     """A compiled SpMV executor: ``plan(x) -> y`` and ``plan.spmm(X) -> Y``.
 
-    ``apply`` / ``apply_multi`` are the raw jitted callables (exposed so
-    benchmarks can ``.lower()`` or time them without re-wrapping).
+    ``kernel(operands, x)`` / ``kernel_multi(operands_multi, X)`` are the
+    jitted executors and ``operands`` / ``operands_multi`` the device
+    arrays they read (exposed so benchmarks and compile checks can
+    ``.lower()`` them); ``apply`` / ``apply_multi`` call them unchecked.
     """
 
-    def __init__(self, matrix, report: PlanReport, apply_fn, apply_multi):
+    def __init__(self, matrix, report: PlanReport, ck_v: R.CompiledKernel,
+                 ck_m: R.CompiledKernel):
         self.matrix = matrix
         self.report = report
-        self.apply = apply_fn
-        self.apply_multi = apply_multi
+        self.kernel = jax.jit(ck_v.kernel)
+        self.kernel_multi = jax.jit(ck_m.kernel)
+        self.operands = ck_v.operands
+        self.operands_multi = ck_m.operands
+
+    def apply(self, x: jnp.ndarray) -> jnp.ndarray:
+        return self.kernel(self.operands, x)
+
+    def apply_multi(self, X: jnp.ndarray) -> jnp.ndarray:
+        return self.kernel_multi(self.operands_multi, X)
 
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         return self.spmv(x)
@@ -365,7 +379,7 @@ def _compile(matrix, fmt, chip, am, backend, chunk_block, width_block,
     ck_m = R.build(matrix, fmt, "spmm", be_m, ctx)
     choice = ck_v.choice if isinstance(ck_v.choice, PM.BlockChoice) else None
     return SpMVPlan(matrix, _report(matrix, fmt, chip, am, ck_v.label, choice),
-                    jax.jit(ck_v.fn), jax.jit(ck_m.fn))
+                    ck_v, ck_m)
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,8 @@ column panel, as the roofline model charges it).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,11 +17,15 @@ from ..core import formats as F
 from ..core.formats import BSR
 from . import bsr_spmm as KP
 from .accum import acc_dtype
-from .cache import cached, is_traced, register_stat
+from .cache import cached, is_traced, register_stat, to_device
 from .registry import (
+    CAP_OK,
     FLOAT_PALLAS_VALUE_DTYPES,
+    Capability,
     CompiledKernel,
     KernelContext,
+    _probe_pallas_dtype,
+    closure_kernel,
     register_kernel,
 )
 
@@ -44,33 +50,43 @@ def bsr_block_row_ids(m: BSR) -> jnp.ndarray:
     return cached(m, "_block_row_ids", "bsr_block_row_ids", build)
 
 
-def bsr_spmv(m: BSR, x: jnp.ndarray) -> jnp.ndarray:
-    bm, bn = m.block_shape
-    blocks = jnp.asarray(m.blocks)  # (nb, bm, bn)
-    bci = jnp.asarray(m.block_col_idx)
+def _operands(m: BSR) -> tuple:
+    return m.blocks, m.block_col_idx, bsr_block_row_ids(m), m.scale
+
+
+def bsr_spmv_arrays(ops, x: jnp.ndarray, shape: tuple, block_shape: tuple):
+    blocks, bci, rows, scale = ops  # blocks (nb, bm, bn)
+    bm, bn = block_shape
     acc = acc_dtype(blocks.dtype, x.dtype)
     xb = jnp.take(x.reshape(-1, bn), bci, axis=0)  # (nb, bn)
-    partial = jnp.einsum("kmn,kn->km", blocks.astype(acc), xb.astype(acc))  # (nb, bm)
-    if m.scale is not None:  # per-block dequant scale on the block partials
-        partial = partial * jnp.asarray(m.scale).astype(acc)[:, None]
-    rows = bsr_block_row_ids(m)
-    ybl = jax.ops.segment_sum(partial, rows, num_segments=m.shape[0] // bm)
+    partial = jnp.einsum("kmn,kn->km", jnp.asarray(blocks).astype(acc),
+                         xb.astype(acc))  # (nb, bm)
+    if scale is not None:  # per-block dequant scale on the block partials
+        partial = partial * jnp.asarray(scale).astype(acc)[:, None]
+    ybl = jax.ops.segment_sum(partial, rows, num_segments=shape[0] // bm)
     return ybl.reshape(-1)
 
 
-def bsr_spmm(m: BSR, X: jnp.ndarray) -> jnp.ndarray:
+def bsr_spmm_arrays(ops, X: jnp.ndarray, shape: tuple, block_shape: tuple):
     """Block-sparse matrix times dense matrix: each block feeds the MXU."""
-    bm, bn = m.block_shape
-    blocks = jnp.asarray(m.blocks)
-    bci = jnp.asarray(m.block_col_idx)
+    blocks, bci, rows, scale = ops
+    bm, bn = block_shape
     acc = acc_dtype(blocks.dtype, X.dtype)
     Xb = jnp.take(X.reshape(-1, bn, X.shape[1]), bci, axis=0)  # (nb, bn, K)
-    partial = jnp.einsum("kmn,knj->kmj", blocks.astype(acc), Xb.astype(acc))  # (nb, bm, K)
-    if m.scale is not None:
-        partial = partial * jnp.asarray(m.scale).astype(acc)[:, None, None]
-    rows = bsr_block_row_ids(m)
-    ybl = jax.ops.segment_sum(partial, rows, num_segments=m.shape[0] // bm)
-    return ybl.reshape(m.shape[0], X.shape[1])
+    partial = jnp.einsum("kmn,knj->kmj", jnp.asarray(blocks).astype(acc),
+                         Xb.astype(acc))  # (nb, bm, K)
+    if scale is not None:
+        partial = partial * jnp.asarray(scale).astype(acc)[:, None, None]
+    ybl = jax.ops.segment_sum(partial, rows, num_segments=shape[0] // bm)
+    return ybl.reshape(shape[0], X.shape[1])
+
+
+def bsr_spmv(m: BSR, x: jnp.ndarray) -> jnp.ndarray:
+    return bsr_spmv_arrays(_operands(m), x, m.shape, m.block_shape)
+
+
+def bsr_spmm(m: BSR, X: jnp.ndarray) -> jnp.ndarray:
+    return bsr_spmm_arrays(_operands(m), X, m.shape, m.block_shape)
 
 
 def bell_pack(m: BSR):
@@ -105,51 +121,71 @@ def bsr_spmm_slotloop(m: BSR, X: jnp.ndarray) -> jnp.ndarray:
 @register_kernel("bsr", "spmv", "xla",
                  description="block gather + per-block einsum + segment-sum")
 def _build_spmv(m: BSR, ctx) -> CompiledKernel:
-    bsr_block_row_ids(m)  # warm the build-once cache host-side
-    return CompiledKernel(lambda x: bsr_spmv(m, x), "xla")
+    return CompiledKernel(
+        functools.partial(bsr_spmv_arrays, shape=m.shape,
+                          block_shape=m.block_shape),
+        "xla", operands=to_device(m, *_operands(m)))
 
 
 @register_kernel("bsr", "spmm", "xla",
                  description="multi-vector block einsum + segment-sum")
 def _build_spmm(m: BSR, ctx) -> CompiledKernel:
-    bsr_block_row_ids(m)
-    return CompiledKernel(lambda X: bsr_spmm(m, X), "xla")
+    return CompiledKernel(
+        functools.partial(bsr_spmm_arrays, shape=m.shape,
+                          block_shape=m.block_shape),
+        "xla", operands=to_device(m, *_operands(m)))
 
 
 @register_kernel("bsr", "spmv", "loop_reference", auto=False,
                  description="BELL slot-loop oracle (single column)")
 def _build_spmv_loop(m: BSR, ctx) -> CompiledKernel:
-    return CompiledKernel(lambda x: bsr_spmm_slotloop(m, x[:, None])[:, 0], "loop")
+    return closure_kernel(lambda x: bsr_spmm_slotloop(m, x[:, None])[:, 0],
+                          "loop")
 
 
 @register_kernel("bsr", "spmm", "loop_reference", auto=False,
                  description="BELL slot-loop oracle")
 def _build_spmm_loop(m: BSR, ctx) -> CompiledKernel:
-    return CompiledKernel(lambda X: bsr_spmm_slotloop(m, X), "loop")
+    return closure_kernel(lambda X: bsr_spmm_slotloop(m, X), "loop")
+
+
+def _probe_bell(m, ctx: KernelContext) -> Capability:
+    """The BELL kernel scalar-prefetches its whole (block-rows, slots)
+    column table into SMEM; a table that cannot fit is refused to compile."""
+    cap = _probe_pallas_dtype(m, ctx)
+    if not cap.ok or m is None:
+        return cap
+    brp = np.asarray(m.block_row_ptr)
+    nbpp = int(max(1, np.diff(brp).max())) if len(brp) > 1 else 1
+    table = KP.bell_table_smem_bytes(len(brp) - 1, nbpp)
+    if table > ctx.chip.smem_bytes - KP.SMEM_RESERVE_BYTES:
+        return Capability(False, f"the BELL block-column table ({table} B "
+                                 "scalar-prefetched) does not fit the SMEM")
+    return CAP_OK
 
 
 def _build_bell_spmm(m: BSR, ctx: KernelContext, interpret: bool) -> CompiledKernel:
     if m.scale is not None:  # probe should have rejected; belt-and-braces
         m = F.dequantize(m)
     bcols, slab = bell_pack(m)
-    bc, bl = jnp.asarray(bcols), jnp.asarray(slab)  # device-put once
     M = m.shape[0]
     label = "pallas-interpret" if interpret else "pallas"
 
-    def fn(X):
+    def kernel(ops, X):
+        bc, bl = ops
         return KP.bell_spmm_arrays(bc, bl, X, interpret=interpret)[:M]
 
-    return CompiledKernel(fn, label)
+    return CompiledKernel(kernel, label, operands=to_device(m, bcols, slab))
 
 
-@register_kernel("bsr", "spmm", "pallas",
+@register_kernel("bsr", "spmm", "pallas", probe=_probe_bell,
                  description="BELL scalar-prefetch MXU kernel",
                  value_dtypes=FLOAT_PALLAS_VALUE_DTYPES)
 def _build_bell_compiled(m: BSR, ctx) -> CompiledKernel:
     return _build_bell_spmm(m, ctx, interpret=False)
 
 
-@register_kernel("bsr", "spmm", "pallas_interpret",
+@register_kernel("bsr", "spmm", "pallas_interpret", probe=_probe_bell,
                  description="BELL scalar-prefetch kernel via the interpreter",
                  value_dtypes=FLOAT_PALLAS_VALUE_DTYPES)
 def _build_bell_interpret(m: BSR, ctx) -> CompiledKernel:
@@ -160,20 +196,20 @@ def _build_bell_spmv(m: BSR, ctx: KernelContext, interpret: bool) -> CompiledKer
     ck = _build_bell_spmm(m, ctx, interpret)
     lane = 8  # thin N=1 is MXU-hostile; the model charges the padded panel
 
-    def fn(x):
-        return ck.fn(jnp.tile(x[:, None], (1, lane)))[:, 0]
+    def kernel(ops, x):
+        return ck.kernel(ops, jnp.tile(x[:, None], (1, lane)))[:, 0]
 
-    return CompiledKernel(fn, ck.label)
+    return CompiledKernel(kernel, ck.label, operands=ck.operands)
 
 
-@register_kernel("bsr", "spmv", "pallas",
+@register_kernel("bsr", "spmv", "pallas", probe=_probe_bell,
                  description="BELL kernel over a lane-padded column panel",
                  value_dtypes=FLOAT_PALLAS_VALUE_DTYPES)
 def _build_bell_spmv_compiled(m: BSR, ctx) -> CompiledKernel:
     return _build_bell_spmv(m, ctx, interpret=False)
 
 
-@register_kernel("bsr", "spmv", "pallas_interpret",
+@register_kernel("bsr", "spmv", "pallas_interpret", probe=_probe_bell,
                  description="lane-padded BELL panel via the interpreter",
                  value_dtypes=FLOAT_PALLAS_VALUE_DTYPES)
 def _build_bell_spmv_interpret(m: BSR, ctx) -> CompiledKernel:

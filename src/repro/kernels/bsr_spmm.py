@@ -26,6 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import BSR
+from ..utils import hw
 from .accum import acc_dtype
 
 
@@ -40,19 +41,36 @@ def _bell_kernel(bc_ref, blk_ref, x_ref, o_ref):
     o_ref[...] += jnp.dot(a, x_ref[...], preferred_element_type=o_ref.dtype)
 
 
+#: SMEM left to the kernel's own scalars beside the prefetched table
+SMEM_RESERVE_BYTES = 32 * 1024
+
+
+def bell_table_smem_bytes(nbr: int, nbpp: int) -> int:
+    """SMEM the scalar-prefetched (nbr, nbpp) int32 column table takes:
+    Mosaic pads its minor dimension to 128 words."""
+    return 4 * nbr * (-(-nbpp // 128) * 128)
+
+
+def bell_vmem_bytes(bm: int, bk: int, n: int, value_bytes: int = 4,
+                    x_bytes: int = 4) -> int:
+    """Working-set claim: double-buffered block, X panel and output tile."""
+    return 2 * (bm * bk * value_bytes + bk * n * x_bytes + bm * n * 4)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "out_dtype"))
 def bell_spmm_arrays(
     bcols: jnp.ndarray,   # (nbr, nbpp) int32
     blocks: jnp.ndarray,  # (nbr, nbpp, bm, bk)
     X: jnp.ndarray,       # (K, N)
     *,
-    interpret: bool = True,
+    interpret: bool,
     out_dtype=None,
 ) -> jnp.ndarray:
     nbr, nbpp, bm, bk = blocks.shape
     K, N = X.shape
     assert K % bk == 0
     odt = out_dtype or acc_dtype(blocks.dtype, X.dtype)
+    claim = bell_vmem_bytes(bm, bk, N, blocks.dtype.itemsize, X.dtype.itemsize)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nbr, nbpp),
@@ -66,6 +84,7 @@ def bell_spmm_arrays(
         _bell_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nbr * bm, N), odt),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=hw.vmem_limit(claim)),
         interpret=interpret,
     )(bcols, blocks, X)
 
@@ -99,17 +118,3 @@ def bell_fill_ratio(m: BSR) -> float:
     lens = brp[1:] - brp[:-1]
     nbpp = int(max(1, lens.max())) if len(lens) else 1
     return nbpp * len(lens) / max(1, int(lens.sum()))
-
-
-def bsr_spmm(m: BSR, X: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
-    bcols, slab = bsr_to_bell(m)
-    y = bell_spmm_arrays(jnp.asarray(bcols), jnp.asarray(slab), X, interpret=interpret)
-    return y[: m.shape[0]]
-
-
-def bsr_spmv(m: BSR, x: jnp.ndarray, *, interpret: bool = True, lane_pad: int = 128) -> jnp.ndarray:
-    """SpMV through the SpMM kernel with x broadcast into a lane-aligned
-    column panel (TPU cannot do thin N=1 efficiently; the roofline model
-    charges the padded width)."""
-    X = jnp.tile(x[:, None], (1, lane_pad))
-    return bsr_spmm(m, X, interpret=interpret)[:, 0]

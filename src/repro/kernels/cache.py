@@ -10,6 +10,7 @@ layer's contract).
 from __future__ import annotations
 
 import jax
+import numpy as np
 
 #: build counters per precompute kind, for regression tests ("preprocessing
 #: happens once per matrix").  Kernel modules add their own keys at import.
@@ -48,16 +49,43 @@ def is_traced(a) -> bool:
     return isinstance(a, jax.core.Tracer)
 
 
-def spmm_by_columns(spmv_fn):
-    """Lift an SpMV closure to the SpMM contract column by column.
+def to_device(m, *arrays) -> tuple:
+    """The device copies of a build's operands (``None`` stays ``None``):
+    the arrays a ``CompiledKernel`` receives as jit arguments.  Copies are
+    kept on the container ``m``, so each host array is put on the device
+    once however many executors (SpMV, SpMM, several plans) read it."""
+    import jax.numpy as jnp
 
-    The loop-reference oracle for multi-vector ops: K separate SpMVs,
-    stacked.  Obviously correct, and independent of every fused SpMM
-    formulation it is used to validate.
+    store = getattr(m, "_device_copies", None)
+    if store is None:
+        store = {}
+        object.__setattr__(m, "_device_copies", store)
+    out = []
+    for a in arrays:
+        if a is None or not isinstance(a, np.ndarray):
+            out.append(None if a is None else jnp.asarray(a))
+            continue
+        hit = store.get(id(a))
+        if hit is None or hit[0] is not a:
+            hit = store[id(a)] = (a, jnp.asarray(a))
+        out.append(hit[1])
+    return tuple(out)
+
+
+def spmm_by_columns(spmv_fn):
+    """Lift an SpMV to the SpMM contract column by column.
+
+    ``spmv_fn(*head, x)`` — a closure ``f(x)`` or a kernel ``f(operands,
+    x)``; the lifted function takes the same leading arguments and an
+    ``(N, K)`` block.  The loop-reference oracle for multi-vector ops: K
+    separate SpMVs, stacked.  Obviously correct, and independent of every
+    fused SpMM formulation it is used to validate.
     """
     import jax.numpy as jnp
 
-    def f(X):
-        return jnp.stack([spmv_fn(X[:, j]) for j in range(X.shape[1])], axis=1)
+    def f(*args):
+        *head, X = args
+        return jnp.stack([spmv_fn(*head, X[:, j]) for j in range(X.shape[1])],
+                         axis=1)
 
     return f

@@ -15,6 +15,11 @@ inside a kernel (padding slots carry ``rid == R`` and fall off the one-hot).
 
 x is held fully VMEM-resident, as in the SELL kernel (the paper's "input
 vector in cache" regime by construction).
+
+This kernel does not lower for TPU: Mosaic refuses the 1-D ``jnp.take`` of
+x in VMEM ("Only 2D gather is supported"), so the registry's probe rejects
+the compiled entry there (``registry.GATHER_UNSUPPORTED``) and the kernel runs
+through the interpreter only, as a parity-tested formulation.
 """
 from __future__ import annotations
 
@@ -24,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import CSR
 from .cache import cached, register_stat
@@ -45,7 +51,8 @@ def _csr_rowsplit_kernel(col_ref, val_ref, rid_ref, x_ref, o_ref, *, R):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("R", "tile_block", "interpret", "out_dtype")
+    jax.jit,
+    static_argnames=("R", "tile_block", "interpret", "out_dtype", "vmem_limit"),
 )
 def csr_rowsplit_arrays(
     col2: jnp.ndarray,   # (T, E) int32
@@ -55,17 +62,14 @@ def csr_rowsplit_arrays(
     *,
     R: int = 8,
     tile_block: int = 8,
-    interpret: bool | None = None,
+    interpret: bool,
     out_dtype=None,
+    vmem_limit: int | None = None,
 ) -> jnp.ndarray:
     """Row-split CSR slabs -> (T, R) row-tile results (original row order).
 
     T must be divisible by ``tile_block`` (pad at prepare time).
-    ``interpret=None`` resolves to compiled on TPU, interpret elsewhere.
     """
-    if interpret is None:
-        from ..utils.hw import pallas_interpret_default
-        interpret = pallas_interpret_default()
     T, E = col2.shape
     assert T % tile_block == 0, (T, tile_block)
     odt = out_dtype or acc_dtype(val2.dtype, x.dtype)
@@ -81,6 +85,7 @@ def csr_rowsplit_arrays(
         ],
         out_specs=pl.BlockSpec((tile_block, R), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((T, R), odt),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(col2, val2, rid2, x)
 
@@ -138,16 +143,6 @@ def csr_rowsplit_prepare(m: CSR, R: int = 8, pad_to: int = 8,
 
     return cached(m, f"_rowsplit_{R}_{pad_to}_{tile_block}",
                   "csr_rowsplit_slabs", build)
-
-
-def csr_rowsplit_spmv(m: CSR, x: jnp.ndarray, *, R: int = 8,
-                      tile_block: int = 8, interpret: bool | None = None) -> jnp.ndarray:
-    """End-to-end convenience wrapper (prepare + kernel + crop)."""
-    col2, val2, rid2, T, E = csr_rowsplit_prepare(m, R=R, tile_block=tile_block)
-    y = csr_rowsplit_arrays(jnp.asarray(col2), jnp.asarray(val2),
-                            jnp.asarray(rid2), x, R=R, tile_block=tile_block,
-                            interpret=interpret)
-    return y.reshape(-1)[: m.n_rows]
 
 
 def rowsplit_vmem_bytes(tile_block: int, E: int, R: int, n: int,

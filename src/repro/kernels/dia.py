@@ -11,16 +11,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.formats import DIA
+from ..utils import hw
 from . import dia_spmv as KP
 from .accum import acc_dtype
-from .cache import cached, register_stat, spmm_by_columns
+from .cache import cached, register_stat, spmm_by_columns, to_device
 from .registry import (
     CAP_OK,
     Capability,
     CompiledKernel,
     KernelContext,
     _probe_pallas_dtype,
-    compiled_probe,
+    closure_kernel,
     register_kernel,
 )
 
@@ -48,29 +49,42 @@ def dia_gather_tables(m: DIA):
     return cached(m, "_gather_tables", "dia_gather_tables", build)
 
 
-def dia_spmv(m: DIA, x: jnp.ndarray) -> jnp.ndarray:
+def _operands(m: DIA) -> tuple:
+    idx, data = dia_gather_tables(m)
+    return idx, data, m.scale
+
+
+def dia_spmv_arrays(ops, x: jnp.ndarray) -> jnp.ndarray:
     """Vectorized DIA: one shift-gather of shape (nd, n), one reduction.
     Quantized containers carry a per-diagonal fp32 scale, applied to the
     (nd, n) product table before the reduction over diagonals."""
-    idx, data = dia_gather_tables(m)
+    idx, data, scale = ops
     if data.shape[0] == 0:
-        return jnp.zeros(m.shape[0], dtype=x.dtype)
+        return jnp.zeros(data.shape[1], dtype=x.dtype)
     acc = acc_dtype(data.dtype, x.dtype)
-    prod = jnp.asarray(data).astype(acc) * jnp.take(x, jnp.asarray(idx), axis=0).astype(acc)
-    if m.scale is not None:
-        prod = prod * jnp.asarray(m.scale).astype(acc)[:, None]
+    prod = jnp.asarray(data).astype(acc) * jnp.take(x, idx, axis=0).astype(acc)
+    if scale is not None:
+        prod = prod * jnp.asarray(scale).astype(acc)[:, None]
     return jnp.sum(prod, axis=0)
 
 
-def dia_spmm(m: DIA, X: jnp.ndarray) -> jnp.ndarray:
-    idx, data = dia_gather_tables(m)
+def dia_spmm_arrays(ops, X: jnp.ndarray) -> jnp.ndarray:
+    idx, data, scale = ops
     if data.shape[0] == 0:
-        return jnp.zeros((m.shape[0], X.shape[1]), dtype=X.dtype)
+        return jnp.zeros((data.shape[1], X.shape[1]), dtype=X.dtype)
     acc = acc_dtype(data.dtype, X.dtype)
     d = jnp.asarray(data).astype(acc)
-    if m.scale is not None:
-        d = d * jnp.asarray(m.scale).astype(acc)[:, None]
-    return jnp.einsum("kn,knj->nj", d, jnp.take(X, jnp.asarray(idx), axis=0).astype(acc))
+    if scale is not None:
+        d = d * jnp.asarray(scale).astype(acc)[:, None]
+    return jnp.einsum("kn,knj->nj", d, jnp.take(X, idx, axis=0).astype(acc))
+
+
+def dia_spmv(m: DIA, x: jnp.ndarray) -> jnp.ndarray:
+    return dia_spmv_arrays(_operands(m), x)
+
+
+def dia_spmm(m: DIA, X: jnp.ndarray) -> jnp.ndarray:
+    return dia_spmm_arrays(_operands(m), X)
 
 
 def dia_spmv_loop(m: DIA, x: jnp.ndarray) -> jnp.ndarray:
@@ -94,7 +108,7 @@ def dia_spmv_loop(m: DIA, x: jnp.ndarray) -> jnp.ndarray:
     return y
 
 
-def dia_prepared(m: DIA, tile: int = 512):
+def dia_prepared(m: DIA, tile: int = KP.TILE_QUANTUM):
     """Host-side Pallas padding (``dia_spmv.dia_prepare``), cached once per
     (container, tile)."""
     return cached(m, f"_dia_prepared_{tile}", "dia_pallas_prepare",
@@ -107,71 +121,80 @@ def dia_prepared(m: DIA, tile: int = 512):
 @register_kernel("dia", "spmv", "xla",
                  description="one (nd, n) shift-gather + reduction")
 def _build_spmv(m: DIA, ctx) -> CompiledKernel:
-    dia_gather_tables(m)  # warm the build-once cache host-side
-    return CompiledKernel(lambda x: dia_spmv(m, x), "xla")
+    return CompiledKernel(dia_spmv_arrays, "xla",
+                          operands=to_device(m, *_operands(m)))
 
 
 @register_kernel("dia", "spmm", "xla",
                  description="multi-vector shift-gather einsum")
 def _build_spmm(m: DIA, ctx) -> CompiledKernel:
-    dia_gather_tables(m)
-    return CompiledKernel(lambda X: dia_spmm(m, X), "xla")
+    return CompiledKernel(dia_spmm_arrays, "xla",
+                          operands=to_device(m, *_operands(m)))
 
 
 @register_kernel("dia", "spmv", "loop_reference", auto=False,
                  description="per-diagonal dynamic_slice chain oracle")
 def _build_spmv_loop(m: DIA, ctx) -> CompiledKernel:
-    return CompiledKernel(lambda x: dia_spmv_loop(m, x), "loop")
+    return closure_kernel(lambda x: dia_spmv_loop(m, x), "loop")
 
 
 @register_kernel("dia", "spmm", "loop_reference", auto=False,
                  description="column-by-column per-diagonal chains")
 def _build_spmm_loop(m: DIA, ctx) -> CompiledKernel:
-    return CompiledKernel(spmm_by_columns(lambda x: dia_spmv_loop(m, x)), "loop")
+    return closure_kernel(spmm_by_columns(lambda x: dia_spmv_loop(m, x)),
+                          "loop")
+
+
+def _pallas_claim(m: DIA, tile: int) -> int:
+    """VMEM claim of the Pallas kernel's tiling for this container."""
+    offsets = np.asarray(m.offsets)
+    n_pad = -(-m.shape[0] // tile) * tile
+    pad0 = max(0, -int(offsets.min()))
+    rows = KP.x_rows(pad0, n_pad, int(offsets.max()))
+    vb = int(np.dtype(np.asarray(m.data).dtype).itemsize)
+    return KP.vmem_bytes(len(offsets), tile, rows, vb)
 
 
 def _probe_dia_pallas(m, ctx: KernelContext) -> Capability:
     cap = _probe_pallas_dtype(m, ctx)
     if not cap.ok or m is None:
         return cap
-    nd = int(np.asarray(m.offsets).shape[0])
-    if nd == 0:
+    if int(np.asarray(m.offsets).shape[0]) == 0:
         return Capability(False, "no stored diagonals (empty DIA)")
-    tile = ctx.tile or 512
-    n_pad = -(-m.shape[0] // tile) * tile
-    vb = int(np.dtype(np.asarray(m.data).dtype).itemsize)
-    claim = nd * tile * vb * 2 + (n_pad + 2 * n_pad) * vb
-    if claim > int(ctx.chip.vmem_bytes * 0.5):
+    tile = ctx.tile or KP.TILE_QUANTUM
+    if tile % KP.TILE_QUANTUM:
+        return Capability(False, f"tile {tile} is not a multiple of "
+                                 f"{KP.TILE_QUANTUM}")
+    if not hw.vmem_fits(_pallas_claim(m, tile), ctx.chip):
         return Capability(False, "diagonal slab + padded x exceed the VMEM budget")
     return CAP_OK
 
 
-_probe_dia_pallas_compiled = compiled_probe(_probe_dia_pallas)
-
-
 def _build_dia_pallas(m: DIA, ctx: KernelContext, interpret: bool) -> CompiledKernel:
-    tile = ctx.tile or 512
-    data, pad0, pad1, offsets, n = dia_prepared(m, tile)
+    tile = ctx.tile or KP.TILE_QUANTUM
+    data, pad0, rows, offsets, n = dia_prepared(m, tile)
     label = "pallas-interpret" if interpret else "pallas"
     if not offsets:
-        return CompiledKernel(lambda x: jnp.zeros(n, dtype=x.dtype), label)
-    dataj = jnp.asarray(data)  # device-put once
-    n_pad = data.shape[1]
+        return closure_kernel(lambda x: jnp.zeros(n, dtype=x.dtype), label)
     # per-diagonal scales ride into the kernel as a static float tuple,
     # exactly like the offsets (both are per-diagonal compile-time facts)
     scales = None if m.scale is None else tuple(
         float(v) for v in np.asarray(m.scale, dtype=np.float64))
+    limit = hw.vmem_limit(_pallas_claim(m, tile))
+    odt = acc_dtype(data.dtype, np.float32)
 
-    def fn(x):
-        x_pad = jnp.pad(x, (pad0, pad1 + (n_pad - n)))
-        y = KP.dia_spmv_arrays(dataj, x_pad, offsets=offsets, tile=tile,
-                               pad0=pad0, interpret=interpret, scales=scales)
-        return y[:n]
+    def kernel(ops, x):
+        (dataj,) = ops
+        y = KP.dia_spmv_arrays(dataj, KP.pad_x(x, pad0, rows, odt),
+                               offsets=offsets, tile=tile, pad0=pad0,
+                               interpret=interpret, scales=scales,
+                               vmem_limit=limit)
+        return y.reshape(-1)[:n]
 
-    return CompiledKernel(fn, label)
+    return CompiledKernel(kernel, label, operands=to_device(m, data))
 
 
-@register_kernel("dia", "spmv", "pallas", probe=_probe_dia_pallas_compiled,
+@register_kernel("dia", "spmv", "pallas", probe=_probe_dia_pallas,
                  description="shifted-window tile kernel, static offsets")
 def _build_dia_pallas_compiled(m: DIA, ctx) -> CompiledKernel:
     return _build_dia_pallas(m, ctx, interpret=False)

@@ -10,30 +10,44 @@ import jax.numpy as jnp
 
 from ..core.formats import ELL
 from .accum import acc_dtype
-from .cache import spmm_by_columns
-from .registry import CompiledKernel, register_kernel
+from .cache import spmm_by_columns, to_device
+from .registry import CompiledKernel, closure_kernel, register_kernel
 
 
-def ell_spmv(m: ELL, x: jnp.ndarray) -> jnp.ndarray:
+def _operands(m: ELL) -> tuple:
+    return m.val, m.col_idx, m.scale
+
+
+def ell_spmv_arrays(ops, x: jnp.ndarray) -> jnp.ndarray:
     """Row-major ELL: one gather of shape (M, W), one reduction over W.
     Reduces in ``acc_dtype`` (>= f32); a quantized container's per-row
     scale is applied to the reduced row sums."""
-    acc = acc_dtype(jnp.asarray(m.val).dtype, x.dtype)
-    gathered = jnp.take(x, jnp.asarray(m.col_idx), axis=0)  # (M, W)
-    y = jnp.sum(jnp.asarray(m.val).astype(acc) * gathered.astype(acc), axis=1)
-    if m.scale is not None:
-        y = y * jnp.asarray(m.scale).astype(acc)
+    val, col, scale = ops
+    acc = acc_dtype(val.dtype, x.dtype)
+    gathered = jnp.take(x, col, axis=0)  # (M, W)
+    y = jnp.sum(jnp.asarray(val).astype(acc) * gathered.astype(acc), axis=1)
+    if scale is not None:
+        y = y * jnp.asarray(scale).astype(acc)
     return y
 
 
-def ell_spmm(m: ELL, X: jnp.ndarray) -> jnp.ndarray:
-    acc = acc_dtype(jnp.asarray(m.val).dtype, X.dtype)
-    gathered = jnp.take(X, jnp.asarray(m.col_idx), axis=0)  # (M, W, K)
-    Y = jnp.einsum("mw,mwk->mk", jnp.asarray(m.val).astype(acc),
+def ell_spmm_arrays(ops, X: jnp.ndarray) -> jnp.ndarray:
+    val, col, scale = ops
+    acc = acc_dtype(val.dtype, X.dtype)
+    gathered = jnp.take(X, col, axis=0)  # (M, W, K)
+    Y = jnp.einsum("mw,mwk->mk", jnp.asarray(val).astype(acc),
                    gathered.astype(acc))
-    if m.scale is not None:
-        Y = Y * jnp.asarray(m.scale).astype(acc)[:, None]
+    if scale is not None:
+        Y = Y * jnp.asarray(scale).astype(acc)[:, None]
     return Y
+
+
+def ell_spmv(m: ELL, x: jnp.ndarray) -> jnp.ndarray:
+    return ell_spmv_arrays(_operands(m), x)
+
+
+def ell_spmm(m: ELL, X: jnp.ndarray) -> jnp.ndarray:
+    return ell_spmm_arrays(_operands(m), X)
 
 
 def ell_spmv_loop(m: ELL, x: jnp.ndarray) -> jnp.ndarray:
@@ -55,22 +69,25 @@ def ell_spmv_loop(m: ELL, x: jnp.ndarray) -> jnp.ndarray:
 @register_kernel("ell", "spmv", "xla",
                  description="one (M, W) gather + width reduction")
 def _build_spmv(m: ELL, ctx) -> CompiledKernel:
-    return CompiledKernel(lambda x: ell_spmv(m, x), "xla")
+    return CompiledKernel(ell_spmv_arrays, "xla",
+                          operands=to_device(m, *_operands(m)))
 
 
 @register_kernel("ell", "spmm", "xla",
                  description="(M, W, K) gather + einsum")
 def _build_spmm(m: ELL, ctx) -> CompiledKernel:
-    return CompiledKernel(lambda X: ell_spmm(m, X), "xla")
+    return CompiledKernel(ell_spmm_arrays, "xla",
+                          operands=to_device(m, *_operands(m)))
 
 
 @register_kernel("ell", "spmv", "loop_reference", auto=False,
                  description="per-jagged-column traversal oracle")
 def _build_spmv_loop(m: ELL, ctx) -> CompiledKernel:
-    return CompiledKernel(lambda x: ell_spmv_loop(m, x), "loop")
+    return closure_kernel(lambda x: ell_spmv_loop(m, x), "loop")
 
 
 @register_kernel("ell", "spmm", "loop_reference", auto=False,
                  description="column-by-column jagged-traversal oracle")
 def _build_spmm_loop(m: ELL, ctx) -> CompiledKernel:
-    return CompiledKernel(spmm_by_columns(lambda x: ell_spmv_loop(m, x)), "loop")
+    return closure_kernel(spmm_by_columns(lambda x: ell_spmv_loop(m, x)),
+                          "loop")

@@ -3,15 +3,19 @@
 Registry entries: ``(hybrid, {spmv, spmm}, {xla, loop_reference})`` plus a
 ``{pallas, pallas_interpret}`` SpMV that composes the DIA and SELL Pallas
 kernels (no Pallas SpMM: the DIA part has none — the plan layer falls back
-to the XLA formulation for multi-vector hybrid execution).
+to the XLA formulation for multi-vector hybrid execution).  On TPU the
+Pallas entry is refused with its SELL part's reason: the SELL kernel does
+not lower there.
 """
 from __future__ import annotations
+
+import numpy as np
 
 from ..core.formats import HybridDIA
 from . import dia as KD
 from . import sell as KS
 from .cache import spmm_by_columns
-from .registry import CompiledKernel, KernelContext, register_kernel
+from .registry import Capability, CompiledKernel, KernelContext, closure_kernel, register_kernel
 
 
 def hybrid_spmv(m: HybridDIA, x):
@@ -26,71 +30,72 @@ def hybrid_spmv_loop(m: HybridDIA, x):
     return KD.dia_spmv_loop(m.dia, x) + KS.sell_spmv_loop(m.rest, x)
 
 
+def _sum_of(ck_d: CompiledKernel, ck_s: CompiledKernel, label: str,
+            choice=None) -> CompiledKernel:
+    """The DIA and SELL parts' executors summed; each keeps its operands."""
+    return CompiledKernel(
+        lambda ops, x: ck_d.kernel(ops[0], x) + ck_s.kernel(ops[1], x),
+        label, choice, (ck_d.operands, ck_s.operands))
+
+
 # --- registry entries -------------------------------------------------------
 
 
 @register_kernel("hybrid", "spmv", "xla",
-                 description="DIA shift-gather + SELL padded-view sum")
+                 description="DIA shift-gather + SELL (padded or flat) sum")
 def _build_spmv(m: HybridDIA, ctx) -> CompiledKernel:
-    KD.dia_gather_tables(m.dia)
-    KS.sell_padded_views(m.rest)
-    return CompiledKernel(lambda x: hybrid_spmv(m, x), "xla")
+    return _sum_of(KD._build_spmv(m.dia, ctx), KS._build_spmv(m.rest, ctx),
+                   "xla")
 
 
 @register_kernel("hybrid", "spmm", "xla",
                  description="multi-vector DIA + SELL composition")
 def _build_spmm(m: HybridDIA, ctx) -> CompiledKernel:
-    KD.dia_gather_tables(m.dia)
-    KS.sell_padded_views(m.rest)
-    return CompiledKernel(lambda X: hybrid_spmm(m, X), "xla")
+    return _sum_of(KD._build_spmm(m.dia, ctx), KS._build_spmm(m.rest, ctx),
+                   "xla")
 
 
 @register_kernel("hybrid", "spmv", "loop_reference", auto=False,
                  description="per-diagonal + per-chunk traversal oracles")
 def _build_spmv_loop(m: HybridDIA, ctx) -> CompiledKernel:
-    return CompiledKernel(lambda x: hybrid_spmv_loop(m, x), "loop")
+    return closure_kernel(lambda x: hybrid_spmv_loop(m, x), "loop")
 
 
 @register_kernel("hybrid", "spmm", "loop_reference", auto=False,
                  description="column-by-column composed traversals")
 def _build_spmm_loop(m: HybridDIA, ctx) -> CompiledKernel:
-    return CompiledKernel(spmm_by_columns(lambda x: hybrid_spmv_loop(m, x)), "loop")
+    return closure_kernel(spmm_by_columns(lambda x: hybrid_spmv_loop(m, x)),
+                          "loop")
 
 
-def _probe_hybrid_pallas(m, ctx: KernelContext, compiled: bool) -> Capability:
-    probe_d = (KD._probe_dia_pallas_compiled if compiled else KD._probe_dia_pallas)
-    probe_s = (KS._probe_sell_pallas_compiled if compiled else KS._probe_sell_pallas)
+def _probe_hybrid_pallas(m, ctx: KernelContext) -> Capability:
     if m is None:
-        return probe_s(None, ctx)
+        return KS._probe_sell_pallas(None, ctx)
     # an empty DIA part is fine here (the SELL remainder carries everything,
     # and the build composes a zeros closure for the DIA half)
-    import numpy as np
     if int(np.asarray(m.dia.offsets).shape[0]):
-        cap_d = probe_d(m.dia, ctx)
+        cap_d = KD._probe_dia_pallas(m.dia, ctx)
         if not cap_d.ok:
             return cap_d
-    return probe_s(m.rest, ctx)
+    return KS._probe_sell_pallas(m.rest, ctx)
 
 
 def _build_hybrid_pallas(m: HybridDIA, ctx: KernelContext, interpret: bool) -> CompiledKernel:
     ck_d = KD._build_dia_pallas(m.dia, ctx, interpret)
-    ck_s = (KS._build_pallas_spmv(m.rest, ctx, interpret)
-            if m.rest.nnz else None)
-    if ck_s is None:
-        return CompiledKernel(ck_d.fn, ck_d.label)
-    return CompiledKernel(lambda x: ck_d.fn(x) + ck_s.fn(x), ck_s.label,
-                          ck_s.choice)
+    if not m.rest.nnz:
+        return ck_d
+    ck_s = KS._build_pallas_spmv(m.rest, ctx, interpret)
+    return _sum_of(ck_d, ck_s, ck_s.label, ck_s.choice)
 
 
-@register_kernel("hybrid", "spmv", "pallas",
-                 probe=lambda m, ctx: _probe_hybrid_pallas(m, ctx, True),
+@register_kernel("hybrid", "spmv", "pallas", probe=_probe_hybrid_pallas,
                  description="composed DIA + SELL Pallas kernels")
 def _build_pallas_compiled(m: HybridDIA, ctx) -> CompiledKernel:
     return _build_hybrid_pallas(m, ctx, interpret=False)
 
 
 @register_kernel("hybrid", "spmv", "pallas_interpret",
-                 probe=lambda m, ctx: _probe_hybrid_pallas(m, ctx, False),
+                 probe=_probe_hybrid_pallas,
                  description="composed DIA + SELL kernels via the interpreter")
 def _build_pallas_interpret(m: HybridDIA, ctx) -> CompiledKernel:
     return _build_hybrid_pallas(m, ctx, interpret=True)

@@ -28,17 +28,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import VALUE_DTYPES, MatrixFreeOperator
+from ..utils import hw
+from . import dia_spmv as KD
 from .accum import acc_dtype
-from .cache import cached, register_stat, spmm_by_columns
+from .cache import cached, register_stat, spmm_by_columns, to_device
 from .registry import (
     CAP_OK,
     Capability,
     CompiledKernel,
     KernelContext,
     _probe_pallas_dtype,
-    compiled_probe,
+    closure_kernel,
     register_kernel,
 )
 
@@ -114,20 +117,26 @@ def _rule_mask(p: int, lo: int, hi: int, dtype) -> np.ndarray:
     return ((i >= lo) & (i < hi)).astype(dtype)
 
 
-def mf_spmv(op: MatrixFreeOperator, x: jnp.ndarray) -> jnp.ndarray:
+def _xla_static(op: MatrixFreeOperator) -> dict:
+    """The static half of the XLA executors: diagonal table, pads, dtype."""
+    pad0, pad1 = _pads(op, op.shape[0])
+    return dict(diags=mf_tables(op), n=op.shape[0], pad0=pad0, pad1=pad1,
+                store_dtype=_storage_dtype(op))
+
+
+def mf_spmv_xla(data, x: jnp.ndarray, *, diags, n, pad0, pad1,
+                store_dtype) -> jnp.ndarray:
     """Vectorized matrix-free SpMV: one shifted stride-1 read per diagonal,
-    reshape-broadcast rule masks, no index loads."""
-    n, _ = op.shape
-    diags = mf_tables(op)
-    acc = acc_dtype(_storage_dtype(op), x.dtype)
-    pad0, pad1 = _pads(op, n)
+    reshape-broadcast rule masks, no index loads.  ``data`` holds the
+    stored lanes (None when every diagonal is generated)."""
+    acc = acc_dtype(store_dtype, x.dtype)
     x_pad = jnp.pad(x, (pad0, pad1)).astype(acc)
     y = jnp.zeros(n, dtype=acc)
     ks = 0
     for off, spec in diags:
         xs = jax.lax.dynamic_slice(x_pad, (pad0 + off,), (n,))
         if spec is None:
-            y = y + jnp.asarray(op.data)[ks].astype(acc) * xs
+            y = y + jnp.asarray(data)[ks].astype(acc) * xs
             ks += 1
             continue
         p, lo, hi, gvr = spec
@@ -139,13 +148,11 @@ def mf_spmv(op: MatrixFreeOperator, x: jnp.ndarray) -> jnp.ndarray:
     return y
 
 
-def mf_spmm(op: MatrixFreeOperator, X: jnp.ndarray) -> jnp.ndarray:
+def mf_spmm_xla(data, X: jnp.ndarray, *, diags, n, pad0, pad1,
+                store_dtype) -> jnp.ndarray:
     """Multi-vector analogue: 2-D shifted slices, masks broadcast over
     columns of the block vector."""
-    n, _ = op.shape
-    diags = mf_tables(op)
-    acc = acc_dtype(_storage_dtype(op), X.dtype)
-    pad0, pad1 = _pads(op, n)
+    acc = acc_dtype(store_dtype, X.dtype)
     X_pad = jnp.pad(X, ((pad0, pad1), (0, 0))).astype(acc)
     b = X.shape[1]
     Y = jnp.zeros((n, b), dtype=acc)
@@ -153,7 +160,7 @@ def mf_spmm(op: MatrixFreeOperator, X: jnp.ndarray) -> jnp.ndarray:
     for off, spec in diags:
         Xs = jax.lax.dynamic_slice(X_pad, (pad0 + off, 0), (n, b))
         if spec is None:
-            Y = Y + jnp.asarray(op.data)[ks].astype(acc)[:, None] * Xs
+            Y = Y + jnp.asarray(data)[ks].astype(acc)[:, None] * Xs
             ks += 1
             continue
         p, lo, hi, gvr = spec
@@ -164,6 +171,14 @@ def mf_spmm(op: MatrixFreeOperator, X: jnp.ndarray) -> jnp.ndarray:
                        * mask[None, :, None]).reshape(n, b)
         Y = Y + contrib
     return Y
+
+
+def mf_spmv(op: MatrixFreeOperator, x: jnp.ndarray) -> jnp.ndarray:
+    return mf_spmv_xla(op.data, x, **_xla_static(op))
+
+
+def mf_spmm(op: MatrixFreeOperator, X: jnp.ndarray) -> jnp.ndarray:
+    return mf_spmm_xla(op.data, X, **_xla_static(op))
 
 
 # ---------------------------------------------------------------------------
@@ -203,26 +218,26 @@ def mf_spmv_loop(op: MatrixFreeOperator, x: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _mf_kernel(*refs, diags, tile, pad0, n_stored):
+def _mf_kernel(*refs, diags, t8, pad0, n_stored):
     if n_stored:
         data_ref, x_ref, o_ref = refs
     else:
         x_ref, o_ref = refs
     i = pl.program_id(0)
-    base = i * tile
-    x = x_ref[...]
-    # TPU needs >= 2-D iota; squeeze back to the (tile,) row-id lane
-    row = base + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0).squeeze(-1)
-    acc = jnp.zeros((tile,), dtype=o_ref.dtype)
+    shape = (t8, KD.LANES)
+    row = (i * (t8 * KD.LANES)
+           + jax.lax.broadcasted_iota(jnp.int32, shape, 0) * KD.LANES
+           + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    acc = jnp.zeros(shape, dtype=o_ref.dtype)
     ks = 0
     for off, spec in diags:  # static unroll over the diagonal set
-        xs = jax.lax.dynamic_slice(x, (base + pad0 + off,), (tile,))
+        xs = KD.shifted_window(x_ref, i, pad0 + off, t8)
         if spec is None:
-            contrib = data_ref[ks, :].astype(o_ref.dtype) * xs.astype(o_ref.dtype)
+            contrib = data_ref[ks].astype(o_ref.dtype) * xs
             ks += 1
         else:
             p, lo, hi, gvr = spec
-            contrib = gvr * xs.astype(o_ref.dtype)
+            contrib = gvr * xs
             if p:
                 r = row % p
                 contrib = jnp.where((r >= lo) & (r < hi), contrib, 0)
@@ -232,76 +247,78 @@ def _mf_kernel(*refs, diags, tile, pad0, n_stored):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("diags", "n_pad", "tile", "pad0", "interpret", "out_dtype"),
+    static_argnames=("diags", "n_pad", "tile", "pad0", "interpret",
+                     "vmem_limit"),
 )
 def mf_spmv_arrays(
-    data,                # (n_stored, n_pad) or None when all generated
-    x_pad: jnp.ndarray,  # (pad0 + n_pad + pad1,)
+    data,                # (n_stored, n_pad // 128, 128) or None when all generated
+    x2: jnp.ndarray,     # (rows, 128) accumulation dtype, from dia_spmv.pad_x
     *,
     diags: tuple,
     n_pad: int,
-    tile: int = 512,
+    tile: int,
     pad0: int,
-    interpret: bool | None = None,
-    out_dtype=None,
+    interpret: bool,
+    vmem_limit: int | None = None,
 ) -> jnp.ndarray:
-    if interpret is None:  # compiled on TPU, interpreter elsewhere
-        from ..utils.hw import pallas_interpret_default
-        interpret = pallas_interpret_default()
+    """One SpMV -> ``(n_pad // 128, 128)``."""
     n_stored = 0 if data is None else data.shape[0]
-    assert n_pad % tile == 0
-    odt = out_dtype or acc_dtype(data.dtype if n_stored else jnp.float32,
-                                 x_pad.dtype)
-    kernel = functools.partial(_mf_kernel, diags=diags, tile=tile, pad0=pad0,
+    t8 = KD.check_tile(tile) // KD.LANES
+    r_pad = n_pad // KD.LANES
+    assert r_pad % t8 == 0
+    kernel = functools.partial(_mf_kernel, diags=diags, t8=t8, pad0=pad0,
                                n_stored=n_stored)
-    in_specs = [pl.BlockSpec((x_pad.shape[0],), lambda i: (0,))]
-    operands = [x_pad]
+    in_specs = [pl.BlockSpec(x2.shape, lambda i: (0, 0))]
+    operands = [x2]
     if n_stored:
-        in_specs.insert(0, pl.BlockSpec((n_stored, tile), lambda i: (0, i)))
+        in_specs.insert(0, pl.BlockSpec((n_stored, t8, KD.LANES),
+                                        lambda i: (0, i, 0)))
         operands.insert(0, data)
     return pl.pallas_call(
         kernel,
-        grid=(n_pad // tile,),
+        grid=(r_pad // t8,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), odt),
+        out_specs=pl.BlockSpec((t8, KD.LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((r_pad, KD.LANES), x2.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(*operands)
 
 
-def mf_prepare(op: MatrixFreeOperator, tile: int = 512):
-    """Host-side Pallas padding: stored lanes padded to a tile multiple,
-    x pads covering every shifted window over the padded grid."""
+def mf_prepare(op: MatrixFreeOperator, tile: int = KD.TILE_QUANTUM):
+    """Host-side Pallas padding: stored lanes padded to a tile multiple and
+    laid out lane-dense, x rows covering every shifted window of the grid."""
 
     def build():
         n, _ = op.shape
-        diags = mf_tables(op)
-        n_pad = -(-n // tile) * tile
-        pad0, pad1 = _pads(op, n_pad)
-        n_stored = op.n_stored
+        n_pad = -(-n // KD.check_tile(tile)) * tile
+        pad0 = max(0, -min(op.offsets))
+        rows = KD.x_rows(pad0, n_pad, max(op.offsets))
         data = None
-        if n_stored:
-            data = np.zeros((n_stored, n_pad), dtype=_storage_dtype(op))
+        if op.n_stored:
+            data = np.zeros((op.n_stored, n_pad), dtype=_storage_dtype(op))
             data[:, :n] = np.asarray(op.data)
-        return data, pad0, pad1, diags, n, n_pad
+            data = data.reshape(op.n_stored, -1, KD.LANES)
+        return data, pad0, rows, mf_tables(op), n, n_pad
 
     return cached(op, f"_mf_prepared_{tile}", "mf_pallas_prepare", build)
 
 
+def _pallas_claim(m: MatrixFreeOperator, tile: int) -> int:
+    n_pad = -(-m.shape[0] // tile) * tile
+    rows = KD.x_rows(max(0, -min(m.offsets)), n_pad, max(m.offsets))
+    return KD.vmem_bytes(m.n_stored, tile, rows, _storage_dtype(m).itemsize)
+
+
 def matrix_free_autotune(m: MatrixFreeOperator, ctx: KernelContext) -> int:
-    """Row-tile pick for the Pallas kernel: the largest power-of-two tile
-    whose stored slab + padded x claim fits the VMEM budget and whose
-    padding waste stays under one tile of useful rows."""
+    """Row-tile pick for the Pallas kernel: the largest tile whose stored
+    slab + resident x claim fits the VMEM budget and whose padding waste
+    stays under one tile of useful rows."""
     n = m.shape[0]
-    vb = _storage_dtype(m).itemsize
-    for tile in (1024, 512, 256, 128):
-        if tile > max(128, n):
-            continue
-        n_pad = -(-n // tile) * tile
-        claim = m.n_stored * tile * vb * 2 + 3 * n_pad * vb
-        if claim <= int(ctx.chip.vmem_bytes * 0.5):
+    for tile in (4 * KD.TILE_QUANTUM, 2 * KD.TILE_QUANTUM):
+        if tile <= n and hw.vmem_fits(_pallas_claim(m, tile), ctx.chip):
             return tile
-    return 128
+    return KD.TILE_QUANTUM
 
 
 # --- registry entries -------------------------------------------------------
@@ -310,27 +327,30 @@ def matrix_free_autotune(m: MatrixFreeOperator, ctx: KernelContext) -> int:
 @register_kernel("matrix_free", "spmv", "xla",
                  description="generated diagonals: shifted reads + iota masks")
 def _build_spmv(op: MatrixFreeOperator, ctx) -> CompiledKernel:
-    mf_tables(op)  # warm the build-once cache host-side
-    return CompiledKernel(lambda x: mf_spmv(op, x), "xla")
+    return CompiledKernel(
+        lambda data, x: mf_spmv_xla(data, x, **_xla_static(op)), "xla",
+        operands=to_device(op, op.data)[0])
 
 
 @register_kernel("matrix_free", "spmm", "xla",
                  description="multi-vector generated-diagonal shifted reads")
 def _build_spmm(op: MatrixFreeOperator, ctx) -> CompiledKernel:
-    mf_tables(op)
-    return CompiledKernel(lambda X: mf_spmm(op, X), "xla")
+    return CompiledKernel(
+        lambda data, X: mf_spmm_xla(data, X, **_xla_static(op)), "xla",
+        operands=to_device(op, op.data)[0])
 
 
 @register_kernel("matrix_free", "spmv", "loop_reference", auto=False,
                  description="per-diagonal clipped-segment oracle, host masks")
 def _build_spmv_loop(op: MatrixFreeOperator, ctx) -> CompiledKernel:
-    return CompiledKernel(lambda x: mf_spmv_loop(op, x), "loop")
+    return closure_kernel(lambda x: mf_spmv_loop(op, x), "loop")
 
 
 @register_kernel("matrix_free", "spmm", "loop_reference", auto=False,
                  description="column-by-column per-diagonal oracles")
 def _build_spmm_loop(op: MatrixFreeOperator, ctx) -> CompiledKernel:
-    return CompiledKernel(spmm_by_columns(lambda x: mf_spmv_loop(op, x)), "loop")
+    return closure_kernel(spmm_by_columns(lambda x: mf_spmv_loop(op, x)),
+                          "loop")
 
 
 def _probe_mf_pallas(m, ctx: KernelContext) -> Capability:
@@ -340,38 +360,33 @@ def _probe_mf_pallas(m, ctx: KernelContext) -> Capability:
     if m.n_diags == 0:
         return Capability(False, "no diagonals (empty descriptor)")
     tile = ctx.tile or matrix_free_autotune(m, ctx)
-    n_pad = -(-m.shape[0] // tile) * tile
-    vb = _storage_dtype(m).itemsize
-    claim = m.n_stored * tile * vb * 2 + 3 * n_pad * vb
-    if claim > int(ctx.chip.vmem_bytes * 0.5):
+    if tile % KD.TILE_QUANTUM:
+        return Capability(False, f"tile {tile} is not a multiple of "
+                                 f"{KD.TILE_QUANTUM}")
+    if not hw.vmem_fits(_pallas_claim(m, tile), ctx.chip):
         return Capability(False, "stored lanes + padded x exceed the VMEM budget")
     return CAP_OK
-
-
-_probe_mf_pallas_compiled = compiled_probe(_probe_mf_pallas)
 
 
 def _build_mf_pallas(op: MatrixFreeOperator, ctx: KernelContext,
                      interpret: bool) -> CompiledKernel:
     tile = ctx.tile or matrix_free_autotune(op, ctx)
-    data, pad0, pad1, diags, n, n_pad = mf_prepare(op, tile)
+    data, pad0, rows, diags, n, n_pad = mf_prepare(op, tile)
     label = "pallas-interpret" if interpret else "pallas"
-    dataj = None if data is None else jnp.asarray(data)  # device-put once
     odt = acc_dtype(_storage_dtype(op), np.float32)
+    limit = hw.vmem_limit(_pallas_claim(op, tile))
 
-    def fn(x):
-        # pad1 was computed against the padded grid, so it already covers
-        # the n_pad - n ghost rows' windows
-        x_pad = jnp.pad(x, (pad0, pad1))
-        y = mf_spmv_arrays(dataj, x_pad, diags=diags, n_pad=n_pad, tile=tile,
-                           pad0=pad0, interpret=interpret, out_dtype=odt)
-        return y[:n]
+    def kernel(dataj, x):
+        y = mf_spmv_arrays(dataj, KD.pad_x(x, pad0, rows, odt), diags=diags,
+                           n_pad=n_pad, tile=tile, pad0=pad0,
+                           interpret=interpret, vmem_limit=limit)
+        return y.reshape(-1)[:n]
 
-    return CompiledKernel(fn, label, choice=tile)
+    return CompiledKernel(kernel, label, tile, to_device(op, data)[0])
 
 
 @register_kernel("matrix_free", "spmv", "pallas",
-                 probe=_probe_mf_pallas_compiled, autotune=matrix_free_autotune,
+                 probe=_probe_mf_pallas, autotune=matrix_free_autotune,
                  description="tiled rows; cols = row + offset in-registers")
 def _build_mf_pallas_compiled(op: MatrixFreeOperator, ctx) -> CompiledKernel:
     return _build_mf_pallas(op, ctx, interpret=False)
@@ -384,17 +399,21 @@ def _build_mf_pallas_interpret(op: MatrixFreeOperator, ctx) -> CompiledKernel:
     return _build_mf_pallas(op, ctx, interpret=True)
 
 
+def _build_mf_pallas_spmm(op, ctx, interpret: bool) -> CompiledKernel:
+    ck = _build_mf_pallas(op, ctx, interpret)
+    return CompiledKernel(spmm_by_columns(ck.kernel), ck.label, ck.choice,
+                          ck.operands)
+
+
 @register_kernel("matrix_free", "spmm", "pallas",
-                 probe=_probe_mf_pallas_compiled, autotune=matrix_free_autotune,
+                 probe=_probe_mf_pallas, autotune=matrix_free_autotune,
                  description="column-by-column over the tiled spmv kernel")
-def _build_mf_pallas_spmm(op: MatrixFreeOperator, ctx) -> CompiledKernel:
-    ck = _build_mf_pallas(op, ctx, interpret=False)
-    return CompiledKernel(spmm_by_columns(ck.fn), ck.label, choice=ck.choice)
+def _build_mf_pallas_spmm_compiled(op: MatrixFreeOperator, ctx) -> CompiledKernel:
+    return _build_mf_pallas_spmm(op, ctx, interpret=False)
 
 
 @register_kernel("matrix_free", "spmm", "pallas_interpret",
                  probe=_probe_mf_pallas, autotune=matrix_free_autotune,
                  description="column-by-column over the interpreted kernel")
 def _build_mf_pallas_spmm_interpret(op: MatrixFreeOperator, ctx) -> CompiledKernel:
-    ck = _build_mf_pallas(op, ctx, interpret=True)
-    return CompiledKernel(spmm_by_columns(ck.fn), ck.label, choice=ck.choice)
+    return _build_mf_pallas_spmm(op, ctx, interpret=True)

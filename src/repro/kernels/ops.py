@@ -39,8 +39,7 @@ def _resolve(backend: str) -> str:
 
 
 def _interpret() -> bool:
-    from ..utils.hw import pallas_interpret_default
-    return pallas_interpret_default()
+    return not on_tpu()
 
 
 def _build(matrix, fmt: str, op: str, backend: str, **ctx_kw):
@@ -84,7 +83,7 @@ def make_sell_spmv(m: SELL, *, backend: str = "auto", chunk_block: int | None = 
 
 
 def make_bsr_spmm(m: BSR, *, backend: str = "auto"):
-    return jax.jit(_build(m, "bsr", "spmm", backend).fn)
+    return _build(m, "bsr", "spmm", backend).jitted()
 
 
 # ---------------------------------------------------------------------------
@@ -92,8 +91,8 @@ def make_bsr_spmm(m: BSR, *, backend: str = "auto"):
 # ---------------------------------------------------------------------------
 
 
-def make_dia_spmv(m: DIA, *, backend: str = "auto", tile: int = 512):
-    return jax.jit(_build(m, "dia", "spmv", backend, tile=tile).fn)
+def make_dia_spmv(m: DIA, *, backend: str = "auto", tile: int | None = None):
+    return _build(m, "dia", "spmv", backend, tile=tile).jitted()
 
 
 def make_hybrid_spmv(m: HybridDIA, *, backend: str = "auto", **kw):
@@ -137,4 +136,4 @@ def make_kernel_spmv(matrix, *, backend: str = "auto", **kw):
     fmt = _FMT_OF.get(type(matrix))
     if fmt is None:
         raise TypeError(f"no kernel path for {type(matrix).__name__}")
-    return jax.jit(_build(matrix, fmt, "spmv", backend, **kw).fn)
+    return _build(matrix, fmt, "spmv", backend, **kw).jitted()

@@ -23,10 +23,11 @@ Key space
                            (the fast path on CPU and the universal fallback);
   - ``pallas``           — compiled Pallas TPU kernels (TPU only);
   - ``pallas_interpret`` — the same kernels through the Pallas interpreter
-                           (runs anywhere; the CI validation mode);
+                           (off-TPU only; the CI validation mode);
   - ``loop_reference``   — the paper-faithful per-diagonal / per-chunk loop
                            traversals: slow, obviously correct, the parity
-                           oracle every other entry is tested against.
+                           oracle every other entry is tested against
+                           (off-TPU only, like the interpreter).
 
 Each :class:`KernelEntry` carries three hooks:
 
@@ -49,6 +50,7 @@ repro.kernels.registry --list`` prints the registered table (the CI
 from __future__ import annotations
 
 import argparse
+import functools
 import sys
 from dataclasses import dataclass
 from typing import Callable
@@ -126,13 +128,35 @@ CAP_OK = Capability(True)
 class CompiledKernel:
     """What a build hook returns: the executor plus its provenance.
 
-    ``fn`` is *not* jitted — callers (the plan layer) jit it exactly once,
-    or run it eagerly (the parity suite, loop oracles).
+    ``kernel(operands, *args)`` is pure in its arguments: the matrix arrays
+    it reads arrive as ``operands`` (a pytree placed on the device once, at
+    build time), so a caller that jits ``kernel`` passes the matrix as jit
+    *arguments* and the compiled program never embeds it as constants.
+    ``kernel`` is *not* jitted — callers (the plan layer) jit it exactly
+    once, or run it eagerly through :meth:`fn` (the parity suite, loop
+    oracles).
     """
 
-    fn: Callable
+    kernel: Callable
     label: str                      # plan-report kernel label ("xla", ...)
     choice: object | None = None    # e.g. perfmodel.BlockChoice (Pallas SELL)
+    operands: object = ()           # pytree of device arrays ``kernel`` reads
+
+    def fn(self, *args):
+        """Eager call: ``kernel`` applied to this build's own operands."""
+        return self.kernel(self.operands, *args)
+
+    def jitted(self) -> Callable:
+        """``f(*args)``: ``kernel`` jitted, with the operands passed to it
+        as arguments on every call."""
+        return functools.partial(jax.jit(self.kernel), self.operands)
+
+
+def closure_kernel(fn: Callable, label: str, choice=None) -> CompiledKernel:
+    """Wrap an executor that reads its container directly (the loop
+    oracles, the slab multiplies whose arrays arrive per call): it takes
+    no operands, so under jit whatever it closes over is a constant."""
+    return CompiledKernel(lambda _ops, *args: fn(*args), label, choice)
 
 
 @dataclass(frozen=True)
@@ -189,12 +213,8 @@ def _probe_ok(matrix, ctx) -> Capability:
 
 
 def compiled_probe(base_probe):
-    """Compose a probe with the compiled-Pallas platform gate.
-
-    One shared implementation of the off-TPU rejection (the per-format
-    Pallas modules wrap their operand probes with this instead of each
-    re-stating the platform predicate and message).
-    """
+    """Compose a probe with the compiled-Pallas platform gate (applied by
+    ``register_kernel`` to every ``pallas`` entry)."""
 
     def probe(matrix, ctx) -> Capability:
         if not on_tpu():
@@ -205,9 +225,30 @@ def compiled_probe(base_probe):
     return probe
 
 
-def _probe_pallas_compiled(matrix, ctx) -> Capability:
-    """Shared platform/dtype gate for compiled-Pallas entries."""
-    return compiled_probe(_probe_pallas_dtype)(matrix, ctx)
+#: backends that exist to validate the compiled paths off the chip: the
+#: Pallas interpreter and the loop oracles.  On a TPU they are never an
+#: auto pick nor a degrade target — a plan that reports one there would
+#: hide the device behind a host-speed executor.
+HOST_ONLY_BACKENDS = ("pallas_interpret", "loop_reference")
+
+
+#: why the compiled gather kernels (SELL, CSR row-split) are refused on TPU
+GATHER_UNSUPPORTED = ("gathers x with a 1-D jnp.take from VMEM, which "
+                      "Mosaic does not lower on TPU (only 2-D gathers)")
+
+
+def host_only_probe(base_probe):
+    """Compose a probe with the host-only gate (applied by
+    ``register_kernel`` to every :data:`HOST_ONLY_BACKENDS` entry)."""
+
+    def probe(matrix, ctx) -> Capability:
+        if on_tpu():
+            return Capability(False, "the interpreter and loop oracles "
+                                     "validate off-TPU; on a TPU the "
+                                     "compiled entries run")
+        return base_probe(matrix, ctx)
+
+    return probe
 
 
 def _operand_value_dtype(matrix) -> str | None:
@@ -292,12 +333,14 @@ def register_kernel(format: str, op: str, backend: str, *, probe=None,
     def deco(build):
         if probe is not None:
             pr = probe
-        elif backend == "pallas":
-            pr = _probe_pallas_compiled
-        elif backend == "pallas_interpret":
+        elif backend in ("pallas", "pallas_interpret"):
             pr = _probe_pallas_dtype
         else:
             pr = _probe_ok
+        if backend == "pallas":
+            pr = compiled_probe(pr)
+        elif backend in HOST_ONLY_BACKENDS:
+            pr = host_only_probe(pr)
         if value_dtypes is not None:
             vd = tuple(value_dtypes)
         elif backend in ("pallas", "pallas_interpret"):
@@ -426,7 +469,9 @@ def select_backend(matrix, format: str, op: str,
         raise BackendUnavailable(
             f"no registered backend can run ({format}, {op}) on this "
             f"platform ({jax.default_backend()})")
-    choice = (min(costs, key=costs.get), costs)
+    # on equal predicted cost the compiled kernel wins: the XLA formulation
+    # is the fallback (only a TPU ever offers both)
+    choice = (min(costs, key=lambda b: (costs[b], b != "pallas")), costs)
     if memo is not None:
         memo[memo_key] = choice
     return choice

@@ -22,23 +22,27 @@ The perfmodel accounts for all three regimes per backend.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.formats import SELL
+from ..utils import hw
 from . import sell_spmv as KP
 from .accum import acc_dtype
-from .cache import cached, register_stat, spmm_by_columns
+from .cache import cached, register_stat, spmm_by_columns, to_device
 from .registry import (
     CAP_OK,
     Capability,
     CompiledKernel,
     KernelContext,
     _probe_pallas_dtype,
-    compiled_probe,
+    closure_kernel,
     register_kernel,
 )
+from . import registry
 
 register_stat("sell_padded_views")
 register_stat("sell_flat_rids")
@@ -91,11 +95,11 @@ def sell_perm_is_natural(m: SELL) -> bool:
 
 
 def _perm_arg(m: SELL):
-    """Device inverse-permutation operand for the kernels, or None for the
-    natural order.  ``inv[orig_row] = tile position of orig_row``: the
-    sigma-sort perm is a bijection on real rows, so undoing it is a single
-    n-element *gather* — never the scatter-add an ``.at[perm].add`` would
-    lower to (serial on XLA:CPU)."""
+    """Inverse-permutation operand for the kernels (host numpy), or None
+    for the natural order.  ``inv[orig_row] = tile position of orig_row``:
+    the sigma-sort perm is a bijection on real rows, so undoing it is a
+    single n-element *gather* — never the scatter-add an ``.at[perm].add``
+    would lower to (serial on XLA:CPU)."""
     if sell_perm_is_natural(m):
         return None
     inv = getattr(m, "_perm_inv", None)
@@ -106,7 +110,7 @@ def _perm_arg(m: SELL):
         pos = np.nonzero(p < n)[0]
         inv[p[pos]] = pos
         object.__setattr__(m, "_perm_inv", inv)
-    return jnp.asarray(inv)
+    return inv
 
 
 def sell_spmv_padded(col3: jnp.ndarray, val3: jnp.ndarray, perm,
@@ -129,13 +133,26 @@ def sell_spmv_padded(col3: jnp.ndarray, val3: jnp.ndarray, perm,
     return flat[:n_rows] if perm is None else flat[perm]
 
 
+def _padded_operands(m: SELL, pad_width_to: int = 1) -> tuple:
+    """(col3, val3, inverse perm, scale) of the padded (nc, W, C) views."""
+    col3, val3, _ = sell_padded_views(m, pad_width_to)
+    return col3, val3, _perm_arg(m), m.scale
+
+
+def _padded_spmv_kernel(ops, x, n_rows: int):
+    col3, val3, perm, scale = ops
+    return sell_spmv_padded(col3, val3, perm, x, n_rows, scale)
+
+
+def _padded_spmm_kernel(ops, X, n_rows: int):
+    col3, val3, perm, scale = ops
+    return sell_spmm_padded(col3, val3, perm, X, n_rows, scale)
+
+
 def sell_spmv(m: SELL, x: jnp.ndarray) -> jnp.ndarray:
     """Vectorized SELL via the cached padded 3-D views: one gather + one
     reduction over W + one perm-scatter (no host loop over chunks)."""
-    col3, val3, _ = sell_padded_views(m)
-    scale = None if m.scale is None else jnp.asarray(m.scale)
-    return sell_spmv_padded(jnp.asarray(col3), jnp.asarray(val3),
-                            _perm_arg(m), x, m.shape[0], scale)
+    return _padded_spmv_kernel(_padded_operands(m), x, m.shape[0])
 
 
 def sell_spmm_padded(col3: jnp.ndarray, val3: jnp.ndarray, perm,
@@ -154,10 +171,7 @@ def sell_spmm_padded(col3: jnp.ndarray, val3: jnp.ndarray, perm,
 
 
 def sell_spmm(m: SELL, X: jnp.ndarray) -> jnp.ndarray:
-    col3, val3, _ = sell_padded_views(m)
-    scale = None if m.scale is None else jnp.asarray(m.scale)
-    return sell_spmm_padded(jnp.asarray(col3), jnp.asarray(val3),
-                            _perm_arg(m), X, m.shape[0], scale)
+    return _padded_spmm_kernel(_padded_operands(m), X, m.shape[0])
 
 
 def sell_spmv_flat(col, val, rid, perm, x, n_rows: int, n_segments: int,
@@ -192,11 +206,18 @@ def sell_spmm_flat(col, val, rid, perm, X, n_rows: int, n_segments: int,
     return tiles[:n_rows] if perm is None else tiles[perm]
 
 
-def _flat_operands(m: SELL):
-    rid = sell_flat_rids(m)
-    scale = None if m.scale is None else jnp.asarray(m.scale)
-    return (jnp.asarray(m.col_idx), jnp.asarray(m.val), jnp.asarray(rid),
-            _perm_arg(m), scale)
+def _flat_operands(m: SELL) -> tuple:
+    return m.col_idx, m.val, sell_flat_rids(m), _perm_arg(m), m.scale
+
+
+def _flat_spmv_kernel(ops, x, n_rows: int, n_segments: int, C: int):
+    col, val, rid, perm, scale = ops
+    return sell_spmv_flat(col, val, rid, perm, x, n_rows, n_segments, C, scale)
+
+
+def _flat_spmm_kernel(ops, X, n_rows: int, n_segments: int, C: int):
+    col, val, rid, perm, scale = ops
+    return sell_spmm_flat(col, val, rid, perm, X, n_rows, n_segments, C, scale)
 
 
 def sell_spmv_loop(m: SELL, x: jnp.ndarray) -> jnp.ndarray:
@@ -270,7 +291,7 @@ def sell_autotune(m: SELL, ctx: KernelContext):
         # re-claim for the overridden tiling, not the model's choice
         claim = int(KP.vmem_bytes(cb, wb, m.C, m.shape[1], vb))
         choice = PM.BlockChoice(cb, wb, -(-W0 // wb) * wb, claim,
-                                claim <= int(ctx.chip.vmem_bytes * 0.5))
+                                hw.vmem_fits(claim, ctx.chip))
     nc = max(1, m.n_chunks)
     while nc % cb:   # nc is fixed by the matrix; cb must divide it
         cb -= 1
@@ -281,6 +302,8 @@ def sell_autotune(m: SELL, ctx: KernelContext):
 
 
 def _probe_sell_pallas(m, ctx: KernelContext) -> Capability:
+    if registry.on_tpu():  # looked up per call: tests fake the platform
+        return Capability(False, registry.GATHER_UNSUPPORTED)
     cap = _probe_pallas_dtype(m, ctx)
     if not cap.ok or m is None:
         return cap
@@ -291,58 +314,49 @@ def _probe_sell_pallas(m, ctx: KernelContext) -> Capability:
     return CAP_OK
 
 
-_probe_sell_pallas_compiled = compiled_probe(_probe_sell_pallas)
-
-
-def _pallas_operands(m: SELL, ctx: KernelContext):
-    choice = sell_autotune(m, ctx)
-    col3, val3, _ = sell_padded_views(m, pad_width_to=choice.width_block)
-    return (choice, jnp.asarray(col3), jnp.asarray(val3),  # device-put once
-            _perm_arg(m))
-
-
 def _build_pallas_spmv(m: SELL, ctx: KernelContext, interpret: bool) -> CompiledKernel:
-    choice, col3, val3, perm = _pallas_operands(m, ctx)
+    choice = sell_autotune(m, ctx)
     cb, wb = choice.chunk_block, choice.width_block
     n = m.shape[0]
-    scale = None if m.scale is None else jnp.asarray(m.scale)
+    limit = hw.vmem_limit(choice.vmem_bytes)
 
-    def fn(x):
+    def kernel(ops, x):
+        col3, val3, perm, scale = ops
         tiles = KP.sell_spmv_arrays(col3, val3, x, chunk_block=cb,
-                                    width_block=wb, interpret=interpret)
+                                    width_block=wb, interpret=interpret,
+                                    vmem_limit=limit)
         if scale is not None:  # per-chunk scale on the reduced (nc, C) tiles
             tiles = tiles * scale.astype(tiles.dtype)[:, None]
         return KP.sell_spmv_scatter(tiles, perm, n)
 
-    return CompiledKernel(fn, "pallas-interpret" if interpret else "pallas",
-                          choice)
+    return CompiledKernel(kernel, "pallas-interpret" if interpret else "pallas",
+                          choice, to_device(m, *_padded_operands(m, wb)))
 
 
 def _build_pallas_spmm(m: SELL, ctx: KernelContext, interpret: bool) -> CompiledKernel:
-    choice, col3, val3, perm = _pallas_operands(m, ctx)
+    choice = sell_autotune(m, ctx)
     cb, wb = choice.chunk_block, choice.width_block
     n = m.shape[0]
     vb = int(np.dtype(np.asarray(m.val).dtype).itemsize)
-    budget = int(ctx.chip.vmem_bytes * 0.5)
-    scale = None if m.scale is None else jnp.asarray(m.scale)
 
-    def fn(X):
+    def kernel(ops, X):
+        col3, val3, perm, scale = ops
         # the probe claims VMEM at k=1 (batch width is unknown until call
         # time); X.shape is static per trace, so re-claim here and degrade
         # to the fused XLA formulation on the same wb-padded views when a
         # wide batch would blow the budget — never emit a doomed kernel
-        k = int(X.shape[1])
-        claim = KP.vmem_bytes(cb, wb, m.C, m.shape[1], vb, k=k)
-        if claim > budget:
+        claim = KP.vmem_bytes(cb, wb, m.C, m.shape[1], vb, k=int(X.shape[1]))
+        if not hw.vmem_fits(claim, ctx.chip):
             return sell_spmm_padded(col3, val3, perm, X, n, scale)
         tiles = KP.sell_spmm_arrays(col3, val3, X, chunk_block=cb,
-                                    width_block=wb, interpret=interpret)
+                                    width_block=wb, interpret=interpret,
+                                    vmem_limit=hw.vmem_limit(claim))
         if scale is not None:
             tiles = tiles * scale.astype(tiles.dtype)[:, None, None]
         return KP.sell_spmm_scatter(tiles, perm, n)
 
-    return CompiledKernel(fn, "pallas-interpret" if interpret else "pallas",
-                          choice)
+    return CompiledKernel(kernel, "pallas-interpret" if interpret else "pallas",
+                          choice, to_device(m, *_padded_operands(m, wb)))
 
 
 # --- registry entries -------------------------------------------------------
@@ -353,14 +367,14 @@ def _build_pallas_spmm(m: SELL, ctx: KernelContext, interpret: bool) -> Compiled
                              "(per-container pick) + perm scatter")
 def _build_spmv(m: SELL, ctx) -> CompiledKernel:
     from ..core import perfmodel as PM
+    n = m.shape[0]
     if PM.sell_xla_uses_flat(m):
-        col, val, rid, perm, scale = _flat_operands(m)
-        nseg, C, n = m.n_chunks * m.C, m.C, m.shape[0]
         return CompiledKernel(
-            lambda x: sell_spmv_flat(col, val, rid, perm, x, n, nseg, C,
-                                     scale), "xla")
-    sell_padded_views(m)  # warm the build-once cache host-side
-    return CompiledKernel(lambda x: sell_spmv(m, x), "xla")
+            functools.partial(_flat_spmv_kernel, n_rows=n,
+                              n_segments=m.n_chunks * m.C, C=m.C),
+            "xla", operands=to_device(m, *_flat_operands(m)))
+    return CompiledKernel(functools.partial(_padded_spmv_kernel, n_rows=n),
+                          "xla", operands=to_device(m, *_padded_operands(m)))
 
 
 @register_kernel("sell", "spmm", "xla",
@@ -368,29 +382,30 @@ def _build_spmv(m: SELL, ctx) -> CompiledKernel:
                              "(per-container pick) + perm scatter")
 def _build_spmm(m: SELL, ctx) -> CompiledKernel:
     from ..core import perfmodel as PM
+    n = m.shape[0]
     if PM.sell_xla_uses_flat(m):
-        col, val, rid, perm, scale = _flat_operands(m)
-        nseg, C, n = m.n_chunks * m.C, m.C, m.shape[0]
         return CompiledKernel(
-            lambda X: sell_spmm_flat(col, val, rid, perm, X, n, nseg, C,
-                                     scale), "xla")
-    sell_padded_views(m)
-    return CompiledKernel(lambda X: sell_spmm(m, X), "xla")
+            functools.partial(_flat_spmm_kernel, n_rows=n,
+                              n_segments=m.n_chunks * m.C, C=m.C),
+            "xla", operands=to_device(m, *_flat_operands(m)))
+    return CompiledKernel(functools.partial(_padded_spmm_kernel, n_rows=n),
+                          "xla", operands=to_device(m, *_padded_operands(m)))
 
 
 @register_kernel("sell", "spmv", "loop_reference", auto=False,
                  description="paper-faithful chunk-local slab traversal")
 def _build_spmv_loop(m: SELL, ctx) -> CompiledKernel:
-    return CompiledKernel(lambda x: sell_spmv_loop(m, x), "loop")
+    return closure_kernel(lambda x: sell_spmv_loop(m, x), "loop")
 
 
 @register_kernel("sell", "spmm", "loop_reference", auto=False,
                  description="column-by-column chunk-slab traversals")
 def _build_spmm_loop(m: SELL, ctx) -> CompiledKernel:
-    return CompiledKernel(spmm_by_columns(lambda x: sell_spmv_loop(m, x)), "loop")
+    return closure_kernel(spmm_by_columns(lambda x: sell_spmv_loop(m, x)),
+                          "loop")
 
 
-@register_kernel("sell", "spmv", "pallas", probe=_probe_sell_pallas_compiled,
+@register_kernel("sell", "spmv", "pallas", probe=_probe_sell_pallas,
                  autotune=sell_autotune,
                  description="chunk-slab grid kernel, VMEM-resident x")
 def _build_pallas_spmv_compiled(m: SELL, ctx) -> CompiledKernel:
@@ -404,7 +419,7 @@ def _build_pallas_spmv_interpret(m: SELL, ctx) -> CompiledKernel:
     return _build_pallas_spmv(m, ctx, interpret=True)
 
 
-@register_kernel("sell", "spmm", "pallas", probe=_probe_sell_pallas_compiled,
+@register_kernel("sell", "spmm", "pallas", probe=_probe_sell_pallas,
                  autotune=sell_autotune,
                  description="multi-vector chunk-slab kernel (one matrix pass)")
 def _build_pallas_spmm_compiled(m: SELL, ctx) -> CompiledKernel:

@@ -21,6 +21,11 @@ TPU tiling:
 
 Grid: (nc/CB, W/WB); the W axis accumulates into the same output block
 (revisited output => sequential W iterations, init at w==0).
+
+Neither kernel lowers for TPU: the in-kernel ``jnp.take`` of x is a 1-D
+gather from VMEM, which Mosaic refuses (``registry.GATHER_UNSUPPORTED``).  The
+registry's probes reject the compiled entries there; the interpreter runs
+them as a parity-tested formulation.
 """
 from __future__ import annotations
 
@@ -29,6 +34,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .accum import acc_dtype
 
 
 def _sell_kernel(col_ref, val_ref, x_ref, o_ref):
@@ -45,12 +53,9 @@ def _sell_kernel(col_ref, val_ref, x_ref, o_ref):
     o_ref[...] += jnp.sum(vals.astype(o_ref.dtype) * g.astype(o_ref.dtype), axis=1)
 
 
-from ..utils.hw import pallas_interpret_default as _auto_interpret
-from .accum import acc_dtype
-
-
 @functools.partial(
-    jax.jit, static_argnames=("chunk_block", "width_block", "interpret", "out_dtype")
+    jax.jit, static_argnames=("chunk_block", "width_block", "interpret",
+                              "out_dtype", "vmem_limit")
 )
 def sell_spmv_arrays(
     col3: jnp.ndarray,
@@ -59,17 +64,15 @@ def sell_spmv_arrays(
     *,
     chunk_block: int = 8,
     width_block: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool,
     out_dtype=None,
+    vmem_limit: int | None = None,
 ) -> jnp.ndarray:
     """col3/val3: (nc, W, C); x: (N,) -> (nc, C) tile results.
 
     nc must be divisible by chunk_block and W by width_block (pad at format
     construction; ``SELL.padded_views(pad_width_to=...)``).
-    ``interpret=None`` resolves to compiled on TPU, interpret elsewhere.
     """
-    if interpret is None:
-        interpret = _auto_interpret()
     nc, W, C = col3.shape
     wb = width_block or W
     assert nc % chunk_block == 0, (nc, chunk_block)
@@ -86,6 +89,7 @@ def sell_spmv_arrays(
         ],
         out_specs=pl.BlockSpec((chunk_block, C), lambda i, w: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nc, C), odt),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(col3, val3, x)
 
@@ -115,7 +119,8 @@ def _sell_mm_kernel(col_ref, val_ref, x_ref, o_ref):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("chunk_block", "width_block", "interpret", "out_dtype")
+    jax.jit, static_argnames=("chunk_block", "width_block", "interpret",
+                              "out_dtype", "vmem_limit")
 )
 def sell_spmm_arrays(
     col3: jnp.ndarray,
@@ -124,8 +129,9 @@ def sell_spmm_arrays(
     *,
     chunk_block: int = 8,
     width_block: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool,
     out_dtype=None,
+    vmem_limit: int | None = None,
 ) -> jnp.ndarray:
     """Multi-vector SELL kernel: col3/val3 (nc, W, C); X (N, K) -> (nc, C, K).
 
@@ -135,8 +141,6 @@ def sell_spmm_arrays(
     SpMV kernel; the VMEM claim grows by the (N + CB*C) * K term, so very
     wide batches on very large x may need a smaller chunk_block.
     """
-    if interpret is None:
-        interpret = _auto_interpret()
     nc, W, C = col3.shape
     wb = width_block or W
     assert nc % chunk_block == 0, (nc, chunk_block)
@@ -154,6 +158,7 @@ def sell_spmm_arrays(
         ],
         out_specs=pl.BlockSpec((chunk_block, C, K), lambda i, w: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nc, C, K), odt),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(col3, val3, X)
 

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from .accum import acc_dtype
-from .registry import CompiledKernel, register_kernel
+from .registry import CompiledKernel, closure_kernel, register_kernel
 
 
 @dataclass(frozen=True)
@@ -104,7 +104,7 @@ for _pack in ("ell", "sell"):
             def _make(pack=_pack, backend=_backend):
                 def build(meta: SlabMeta, ctx) -> CompiledKernel:
                     fn = _BUILDERS[(pack, backend)](meta.rows_pp)
-                    return CompiledKernel(fn, "xla" if backend == "xla" else "loop")
+                    return closure_kernel(fn, "xla" if backend == "xla" else "loop")
                 return build
             register_kernel(
                 f"slab_{_pack}", _op, _backend,

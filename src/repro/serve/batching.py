@@ -16,6 +16,7 @@ what the tests and the injectable ``clock`` rely on.
 """
 from __future__ import annotations
 
+import functools
 from collections import deque
 from dataclasses import dataclass
 
@@ -340,12 +341,13 @@ class OperatorQueue:
         """Jitted X -> (verdict, columns) with the *spmm inlined*: one
         compiled program for execute + per-column finiteness + split.
 
-        ``plan.apply_multi`` is itself a jitted callable, so tracing it
+        ``plan.kernel_multi`` is itself a jitted callable, so tracing it
         here inlines the kernel and lets XLA fuse the ``isfinite``
         reduction and the column copies into the spmm's own output pass —
         the no-silent-NaN guarantee becomes close to free, which is what
         keeps the guardrails-overhead gate (``check_bench --bound``)
-        honest.  Only local ``SpMVPlan``s take this path (distributed
+        honest.  The plan's operands enter as arguments, so the program
+        built per batch width never embeds the matrix.  Only local ``SpMVPlan``s take this path (distributed
         plans keep their own fault points and collectives observable);
         the resilience layer also skips it whenever a fault is armed on
         ``plan.spmm``, so chaos tests still drive the exact production
@@ -356,13 +358,13 @@ class OperatorQueue:
         if fn is None:
             from ..core.plan import SpMVPlan
             if isinstance(self.plan, SpMVPlan):
-                inner = self.plan.apply_multi
+                inner, ops = self.plan.kernel_multi, self.plan.operands_multi
 
-                def run(X, _inner=inner, _k=k):
-                    Y = _inner(X)
+                def run(ops, X, _inner=inner, _k=k):
+                    Y = _inner(ops, X)
                     return (jnp.all(jnp.isfinite(Y[:, :_k]), axis=0),
                             tuple(Y[:, i] for i in range(_k)))
-                fn = jax.jit(run)
+                fn = functools.partial(jax.jit(run), ops)
             else:
                 fn = False  # cache the miss; cleared on degrade()
             self._executors[key] = fn

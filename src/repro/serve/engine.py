@@ -285,8 +285,9 @@ class BatchingSpMVServer:
         policy = self._policy(_as_csr(matrix), max_batch, deadline_s, max_pending)
         # the inner slab multiplies know exactly two backends (xla and the
         # loop oracles — see ``_resolve_slab_backend``), so the distributed
-        # ladder is at most one rung
-        ladder = ([] if plan.slab_backend == "loop_reference"
+        # ladder is at most one rung, and none on a TPU (host-only oracles)
+        from ..kernels.registry import on_tpu
+        ladder = ([] if plan.slab_backend == "loop_reference" or on_tpu()
                   else ["loop_reference"])
 
         def rebuild(be, _m=matrix, _mesh=mesh, _v=variant, _cfg=cfg):

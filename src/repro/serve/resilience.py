@@ -31,7 +31,8 @@ shrinks the failure domain back to one request:
   consecutive kernel failures trip the operator's breaker, which recompiles
   its plan one step down the backend ladder (``pallas -> xla ->
   loop_reference``, filtered through the kernel registry's capability
-  probes).  A tripped-and-degraded operator retries immediately on the new
+  probes; on a TPU only ``pallas -> xla`` — the interpreter and the loop
+  oracles are host-only).  A tripped-and-degraded operator retries immediately on the new
   backend; the ladder is finite, so so is the recovery loop.
 
 Everything here is cooperative and synchronous, like the batcher it guards:
@@ -163,6 +164,8 @@ def degradation_ladder(fmt: str, kernel_label: str, matrix=None) -> list[str]:
     for be in below:
         if not (R.has(fmt, "spmv", be) and R.has(fmt, "spmm", be)):
             continue
+        if R.on_tpu() and be in R.HOST_ONLY_BACKENDS:
+            continue  # never step from the chip onto a host-speed executor
         if matrix is not None:
             ctx = R.KernelContext()
             if not (R.get(fmt, "spmv", be).probe(matrix, ctx).ok

@@ -17,11 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map as _shard_map  # noqa: F401
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # noqa: F401
-
 
 def quantize_int8(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Symmetric per-tensor int8 quantization; returns (q, scale)."""

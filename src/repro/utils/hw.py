@@ -28,6 +28,7 @@ class ChipSpec:
     ici_bytes_per_s_per_link: float
     ici_links: int  # links per chip on a 2D torus (v5e: 4; 3D torus v4: 6)
     vmem_bytes: int
+    smem_bytes: int = 1024**2
     mxu_shape: tuple = (128, 128)
     vpu_lanes: int = 128
     vpu_sublanes: int = 8
@@ -54,6 +55,56 @@ SHANGHAI = ChipSpec("shanghai", 8 * 4 * 2.4e9, 8 * 4 * 2.4e9, 20e9, 16 * 1024**3
 NEHALEM = ChipSpec("nehalem", 8 * 4 * 2.66e9, 8 * 4 * 2.66e9, 35e9, 24 * 1024**3, 0.0, 0, 8 * 1024**2)
 
 CHIPS = {c.name: c for c in (TPU_V5E, WOODCREST, SHANGHAI, NEHALEM)}
+
+#: ``jax.Device.device_kind`` -> ChipSpec: the one table that says which
+#: accelerator a run is on.  A TPU kind missing here is an error, never a
+#: silent default.
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def chip_for_device(device=None) -> ChipSpec:
+    """The ChipSpec of ``device`` (default: the first JAX device).
+
+    Raises ValueError off-TPU and for a TPU kind not in ``DEVICE_KINDS``.
+    """
+    import jax
+
+    d = device if device is not None else jax.devices()[0]
+    if d.platform != "tpu":
+        raise ValueError(f"{d.platform} device {d.device_kind!r} is not a TPU")
+    try:
+        return DEVICE_KINDS[d.device_kind]
+    except KeyError:
+        raise ValueError(f"unknown TPU kind {d.device_kind!r}; add it to "
+                         "utils.hw.DEVICE_KINDS") from None
+
+
+# ---------------------------------------------------------------------------
+# Pallas VMEM budget: one rule for the kernels' compiler limit and the probes
+# ---------------------------------------------------------------------------
+
+#: share of a core's VMEM a kernel may reserve; the rest stays with Mosaic's
+#: own internal scratch
+VMEM_BUDGET_FRACTION = 0.75
+#: added to a kernel's buffer claim for its in-register temporaries' spills
+VMEM_HEADROOM_BYTES = 1024**2
+
+
+def vmem_limit(claim: int) -> int:
+    """The ``vmem_limit_bytes`` a kernel passes to Mosaic for a working
+    set of ``claim`` bytes (its double-buffered blocks + resident vectors)."""
+    return int(claim) + VMEM_HEADROOM_BYTES
+
+
+def vmem_budget(chip: ChipSpec) -> int:
+    """The largest ``vmem_limit`` any kernel may request on ``chip``."""
+    return int(chip.vmem_bytes * VMEM_BUDGET_FRACTION)
+
+
+def vmem_fits(claim: int, chip: ChipSpec) -> bool:
+    """The probes' test: would the kernel's limit stay inside the budget?"""
+    return vmem_limit(claim) <= vmem_budget(chip)
+
 
 
 @dataclass(frozen=True)
@@ -130,14 +181,6 @@ def roofline(
         bytes_hbm=bytes_hbm,
         bytes_collective=bytes_collective,
     )
-
-
-def pallas_interpret_default() -> bool:
-    """Single source of truth for the Pallas execution mode: compiled on
-    TPU, interpreter everywhere else (the CPU/test fallback)."""
-    import jax
-
-    return jax.default_backend() != "tpu"
 
 
 def model_flops_per_token(n_params_active: float) -> float:

@@ -104,10 +104,7 @@ def test_parse_collectives_synthetic():
 
 def test_parse_collectives_real_psum():
     from repro.utils.hlo import parse_collectives
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("d",))
     f = shard_map(lambda x: jax.lax.psum(x, "d"), mesh=mesh,

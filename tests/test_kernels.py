@@ -78,7 +78,7 @@ def test_bsr_vs_dense(hh_small):
 
 # --- DIA ------------------------------------------------------------------
 
-@pytest.mark.parametrize("tile", [64, 256])
+@pytest.mark.parametrize("tile", [4096, 8192])  # multiples of TILE_QUANTUM
 def test_dia_kernel(tile):
     m = holstein_hubbard_surrogate(500, seed=2)
     hyb = F.split_dia(m)
@@ -100,7 +100,7 @@ def test_dia_negative_offsets():
             data[k, n - off :] = 0.0
     dia = F.DIA(offsets, data, (n, n))
     x = jnp.asarray(np.random.default_rng(1).standard_normal(n).astype(np.float32))
-    y = np.asarray(dia_spmv(dia, x, tile=50, interpret=True))
+    y = np.asarray(dia_spmv(dia, x, tile=4096, interpret=True))
     np.testing.assert_allclose(y, dia.to_dense() @ np.asarray(x), rtol=1e-4, atol=1e-4)
 
 

@@ -46,7 +46,7 @@ _OP_CACHE: dict = {}
 
 def _x64_ctx(dtype):
     if dtype == np.float64:
-        return jax.experimental.enable_x64()
+        return jax.enable_x64(True)
     import contextlib
     return contextlib.nullcontext()
 

@@ -191,3 +191,31 @@ def test_distributed_plan(hh_small):
         assert plan.slab_format in ("ell", "sell")
         np.testing.assert_allclose(np.asarray(plan(x)), y_ref, rtol=2e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(plan.spmm(X)), Y_ref, rtol=2e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("fmt", ["csr", "ell", "sell", "hybrid", "dia", "auto"])
+def test_lowered_plan_does_not_grow_with_nnz(fmt):
+    """A plan's program takes the matrix as arguments: doubling N (and so
+    nnz) leaves the lowered StableHLO within 10% of its length."""
+    from repro.core.planconfig import PlanConfig
+
+    def hlo_len(n):
+        m = holstein_hubbard_surrogate(n, seed=0)
+        cfg = PlanConfig(format=fmt) if fmt == "auto" else PlanConfig()
+        obj = m if fmt == "auto" else F.convert(m, fmt)
+        plan = SpMVPlan.compile(obj, cfg)
+        x = jnp.zeros(n, jnp.float32)
+        return len(plan.kernel.lower(plan.operands, x).as_text())
+
+    small, large = hlo_len(4_000), hlo_len(8_000)
+    assert abs(large - small) <= 0.1 * small, (small, large)
+
+
+def test_compile_cache_dir_follows_the_environment(monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR when set, else a fixed <repo>/.jax_cache."""
+    from repro.utils import compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+    assert compile_cache.cache_dir() == "/elsewhere/jax"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache.cache_dir() == str(compile_cache.REPO_ROOT / ".jax_cache")
+    assert (compile_cache.REPO_ROOT / "chip_smoke.py").is_file()

@@ -166,10 +166,7 @@ def test_error_feedback_unbiased_over_steps():
 def test_psum_compressed_single_device():
     """shard_map psum of the compressed gradient == plain mean on 1 device."""
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("dp",))
     g = {"w": jax.random.normal(jax.random.PRNGKey(2), (64,))}
     r = GC.init_residuals(g)
