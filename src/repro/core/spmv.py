@@ -21,6 +21,7 @@ from functools import partial
 
 import jax
 
+from ..kernels import registry as _registry
 from ..kernels.bsr import bsr_block_row_ids, bsr_spmm, bsr_spmv  # noqa: F401
 from ..kernels.cache import precompute_stats  # noqa: F401
 from ..kernels.coo import coo_spmm, coo_spmv  # noqa: F401
@@ -37,11 +38,7 @@ from ..kernels.dia import (  # noqa: F401
     dia_spmv_loop,
 )
 from ..kernels.ell import ell_spmm, ell_spmv, ell_spmv_loop  # noqa: F401
-from ..kernels.hybrid import (  # noqa: F401
-    hybrid_spmm,
-    hybrid_spmv,
-    hybrid_spmv_loop,
-)
+from ..kernels.hybrid import hybrid_spmv_loop  # noqa: F401
 from ..kernels.jds import (  # noqa: F401
     jds_segment_ids,
     jds_spmm,
@@ -62,6 +59,14 @@ from .formats import BSR, COO, CSR, DIA, ELL, JDS, SELL, HybridDIA
 # type dispatch (format container -> default XLA formulation)
 # ---------------------------------------------------------------------------
 
+
+def _registry_xla(fmt: str, op: str):
+    """The registry's XLA entry for ``fmt`` — for formats whose XLA
+    formulation exists only as a build hook (hybrid: the sum of its parts'
+    entries)."""
+    return lambda matrix, x: _registry.build(matrix, fmt, op, "xla").fn(x)
+
+
 _DISPATCH = {
     COO: coo_spmv,
     CSR: csr_spmv,
@@ -70,7 +75,7 @@ _DISPATCH = {
     SELL: sell_spmv,
     BSR: bsr_spmv,
     DIA: dia_spmv,
-    HybridDIA: hybrid_spmv,
+    HybridDIA: _registry_xla("hybrid", "spmv"),
 }
 
 _DISPATCH_MM = {
@@ -81,7 +86,7 @@ _DISPATCH_MM = {
     SELL: sell_spmm,
     BSR: bsr_spmm,
     DIA: dia_spmm,
-    HybridDIA: hybrid_spmm,
+    HybridDIA: _registry_xla("hybrid", "spmm"),
 }
 
 

@@ -53,7 +53,10 @@ def to_device(m, *arrays) -> tuple:
     """The device copies of a build's operands (``None`` stays ``None``):
     the arrays a ``CompiledKernel`` receives as jit arguments.  Copies are
     kept on the container ``m``, so each host array is put on the device
-    once however many executors (SpMV, SpMM, several plans) read it."""
+    once however many executors (SpMV, SpMM, several plans) read it.
+    A build traced inside ``jax.jit`` still gets concrete copies (never
+    tracers that would outlive the trace in the store)."""
+    import jax
     import jax.numpy as jnp
 
     store = getattr(m, "_device_copies", None)
@@ -61,14 +64,15 @@ def to_device(m, *arrays) -> tuple:
         store = {}
         object.__setattr__(m, "_device_copies", store)
     out = []
-    for a in arrays:
-        if a is None or not isinstance(a, np.ndarray):
-            out.append(None if a is None else jnp.asarray(a))
-            continue
-        hit = store.get(id(a))
-        if hit is None or hit[0] is not a:
-            hit = store[id(a)] = (a, jnp.asarray(a))
-        out.append(hit[1])
+    with jax.ensure_compile_time_eval():
+        for a in arrays:
+            if a is None or not isinstance(a, np.ndarray):
+                out.append(None if a is None else jnp.asarray(a))
+                continue
+            hit = store.get(id(a))
+            if hit is None or hit[0] is not a:
+                hit = store[id(a)] = (a, jnp.asarray(a))
+            out.append(hit[1])
     return tuple(out)
 
 

@@ -3,7 +3,10 @@
 Registry entries: ``(hybrid, {spmv, spmm}, {xla, loop_reference})`` plus a
 ``{pallas, pallas_interpret}`` SpMV that composes the DIA and SELL Pallas
 kernels (no Pallas SpMM: the DIA part has none — the plan layer falls back
-to the XLA formulation for multi-vector hybrid execution).  On TPU the
+to the XLA formulation for multi-vector hybrid execution).  The XLA entries
+are the sum of the DIA and SELL parts' own XLA entries, so the SELL part
+takes the padded or flat formulation by ``perfmodel.sell_xla_uses_flat``,
+as a SELL matrix would.  On TPU the
 Pallas entry is refused with its SELL part's reason: the SELL kernel does
 not lower there.
 """
@@ -16,14 +19,6 @@ from . import dia as KD
 from . import sell as KS
 from .cache import spmm_by_columns
 from .registry import Capability, CompiledKernel, KernelContext, closure_kernel, register_kernel
-
-
-def hybrid_spmv(m: HybridDIA, x):
-    return KD.dia_spmv(m.dia, x) + KS.sell_spmv(m.rest, x)
-
-
-def hybrid_spmm(m: HybridDIA, X):
-    return KD.dia_spmm(m.dia, X) + KS.sell_spmm(m.rest, X)
 
 
 def hybrid_spmv_loop(m: HybridDIA, x):

@@ -12,6 +12,7 @@ only translate the legacy backend names and jit the result.
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -74,7 +75,7 @@ def make_sell_spmv(m: SELL, *, backend: str = "auto", chunk_block: int | None = 
     plan = SpMVPlan.compile(m, PlanConfig(backend=backend,
                                           chunk_block=chunk_block,
                                           width_block=width_pad))
-    return plan.apply
+    return functools.partial(plan.kernel, plan.operands)
 
 
 # ---------------------------------------------------------------------------
@@ -95,10 +96,11 @@ def make_dia_spmv(m: DIA, *, backend: str = "auto", tile: int | None = None):
     return _build(m, "dia", "spmv", backend, tile=tile).jitted()
 
 
-def make_hybrid_spmv(m: HybridDIA, *, backend: str = "auto", **kw):
-    f_dia = make_dia_spmv(m.dia, backend=backend)
-    f_sell = make_sell_spmv(m.rest, backend=backend, **kw)
-    return jax.jit(lambda x: f_dia(x) + f_sell(x))
+def make_hybrid_spmv(m: HybridDIA, *, backend: str = "auto",
+                     chunk_block: int | None = None,
+                     width_pad: int | None = None):
+    return _build(m, "hybrid", "spmv", backend, chunk_block=chunk_block,
+                  width_block=width_pad).jitted()
 
 
 # ---------------------------------------------------------------------------

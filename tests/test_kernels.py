@@ -78,20 +78,29 @@ def test_bsr_vs_dense(hh_small):
 
 # --- DIA ------------------------------------------------------------------
 
+#: three 4096-row tiles, the last one partial: every test below runs the
+#: kernel at program_id > 0 and reads windows across tile edges
+N_MULTI_TILE = 2 * 4096 + 300
+
+
 @pytest.mark.parametrize("tile", [4096, 8192])  # multiples of TILE_QUANTUM
 def test_dia_kernel(tile):
-    m = holstein_hubbard_surrogate(500, seed=2)
+    n = N_MULTI_TILE
+    m = holstein_hubbard_surrogate(n, seed=2)
     hyb = F.split_dia(m)
-    x = jnp.asarray(np.random.default_rng(1).standard_normal(500).astype(np.float32))
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(n).astype(np.float32))
     y = np.asarray(dia_spmv(hyb.dia, x, tile=tile, interpret=True))
     y_ref = hyb.dia.to_dense() @ np.asarray(x)
     np.testing.assert_allclose(y, y_ref, rtol=1e-4, atol=1e-4)
 
 
 def test_dia_negative_offsets():
-    offsets = np.asarray([-3, 0, 5], np.int32)
-    n = 100
-    data = np.random.default_rng(0).standard_normal((3, n)).astype(np.float32)
+    # offsets on both sides, lane-aligned and not, and past a whole tile:
+    # the shifted windows start in the previous/next tile and straddle
+    # 128-lane rows (the next-row select of shifted_window)
+    offsets = np.asarray([-4200, -300, -129, -3, 0, 5, 128, 131, 4097], np.int32)
+    n = N_MULTI_TILE
+    data = np.random.default_rng(0).standard_normal((len(offsets), n)).astype(np.float32)
     # zero out-of-range slots as the format requires
     for k, off in enumerate(offsets):
         if off < 0:
@@ -100,8 +109,11 @@ def test_dia_negative_offsets():
             data[k, n - off :] = 0.0
     dia = F.DIA(offsets, data, (n, n))
     x = jnp.asarray(np.random.default_rng(1).standard_normal(n).astype(np.float32))
-    y = np.asarray(dia_spmv(dia, x, tile=4096, interpret=True))
-    np.testing.assert_allclose(y, dia.to_dense() @ np.asarray(x), rtol=1e-4, atol=1e-4)
+    y_ref = dia.to_dense() @ np.asarray(x)
+    for tile in (4096, 8192):
+        y = np.asarray(dia_spmv(dia, x, tile=tile, interpret=True))
+        np.testing.assert_allclose(y, y_ref, rtol=1e-4, atol=1e-4,
+                                   err_msg=f"tile={tile}")
 
 
 # --- grouped GEMM ----------------------------------------------------------

@@ -168,6 +168,26 @@ def test_boundary_rows_masked(name):
             np.testing.assert_array_equal(y, np.asarray(dense)[:, col])
 
 
+@pytest.mark.parametrize("tile", [None, 4096], ids=("autotuned", "t4096"))
+def test_pallas_interpret_multi_tile(tile):
+    """The corpus operators fit one row tile; a 9600-row 3-D Laplacian
+    runs the kernel over 2-3 tiles, so the periodic row rules and shifted
+    windows are checked at program_id > 0 and across tile edges."""
+    from repro.core.matrices import laplacian_3d
+
+    m = laplacian_3d(24, 20, 20, dtype=np.float32)
+    mf = F.detect_matrix_free(m)
+    ctx = R.KernelContext(tile=tile)
+    dense = np.asarray(m.to_dense(), np.float64)
+    for op_name in ("spmv", "spmm"):
+        ck = R.build(mf, "matrix_free", op_name, "pallas_interpret", ctx)
+        assert m.shape[0] > ck.choice, ck.choice   # at least two tiles
+        x = _operand(m.shape[1], op_name, np.float32)
+        ref = dense @ np.asarray(x, np.float64)
+        np.testing.assert_allclose(np.asarray(ck.fn(x)), ref,
+                                   rtol=1e-4, atol=1e-4, err_msg=op_name)
+
+
 def test_f64_rejected_by_pallas_probes():
     mf = _mf("laplace2d", np.float64)
     caps = R.capabilities(mf, "matrix_free", "spmv")

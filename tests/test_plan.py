@@ -211,6 +211,25 @@ def test_lowered_plan_does_not_grow_with_nnz(fmt):
     assert abs(large - small) <= 0.1 * small, (small, large)
 
 
+@pytest.mark.parametrize("fmt", ["sell", "hybrid", "dia"])
+def test_kernel_entry_does_not_grow_with_nnz(fmt):
+    """``ops.make_kernel_spmv`` returns the jitted kernel with the matrix
+    bound as its leading arguments (no outer jit closing over it again):
+    doubling N leaves the lowered StableHLO within 10% of its length."""
+    from repro.kernels import ops
+
+    def hlo_len(n):
+        m = F.convert(holstein_hubbard_surrogate(n, seed=0), fmt)
+        f = ops.make_kernel_spmv(m)
+        x = jnp.asarray(np.random.default_rng(0).standard_normal(n), jnp.float32)
+        np.testing.assert_allclose(np.asarray(f(x)), m.to_dense() @ np.asarray(x),
+                                   rtol=2e-4, atol=1e-4)
+        return len(f.func.lower(*f.args, x).as_text())
+
+    small, large = hlo_len(4_000), hlo_len(8_000)
+    assert abs(large - small) <= 0.1 * small, (small, large)
+
+
 def test_compile_cache_dir_follows_the_environment(monkeypatch):
     """$JAX_COMPILATION_CACHE_DIR when set, else a fixed <repo>/.jax_cache."""
     from repro.utils import compile_cache

@@ -53,6 +53,24 @@ def test_make_spmv_jitted(hh_small):
     np.testing.assert_allclose(np.asarray(y2), 2 * np.asarray(y1), rtol=1e-5)
 
 
+def test_hybrid_facade_traced_then_eager(hh_small):
+    """The facade's hybrid dispatch is the registry's XLA entry; building
+    it inside a trace first must leave no tracer in the device-copy store
+    for the eager and plan calls that follow."""
+    from repro.core.plan import SpMVPlan
+
+    m = F.convert(hh_small, "hybrid")
+    n = hh_small.shape[1]
+    X = jnp.asarray(np.random.default_rng(0).standard_normal((n, 3)).astype(np.float32))
+    d = np.asarray(m.to_dense())
+    y = np.asarray(S.make_spmv(m)(X[:, 0]))
+    np.testing.assert_allclose(y, d @ np.asarray(X[:, 0]), rtol=2e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(S.spmm(m, X)), d @ np.asarray(X),
+                               rtol=2e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(SpMVPlan.compile(m)(X[:, 0])), y,
+                               rtol=1e-5, atol=1e-5)
+
+
 def test_flops_accounting(hh_small):
     assert S.flops_of(hh_small) == 2 * hh_small.nnz
 
