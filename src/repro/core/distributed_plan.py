@@ -43,6 +43,7 @@ import numpy as np
 from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..utils import spans
 from ..utils.hw import ChipSpec, TPU_V5E
 from . import perfmodel as PM
 from .distributed import make_mesh_1d, nnz_balanced_partition, row_balanced_partition
@@ -52,14 +53,16 @@ from .plan import PlanReport
 SLAB_FORMATS = ("ell", "sell")
 VARIANTS = ("allgather", "ring", "overlap")
 
-# build counters, mirroring core.spmv.precompute_stats: regression tests
-# assert each shard is packed exactly once per (matrix, plan-key)
-_PACK_STATS = {"shard_packs": 0, "format_selections": 0}
+# build counters in utils.spans, mirroring core.spmv.precompute_stats:
+# regression tests assert each shard is packed exactly once per
+# (matrix, plan-key)
+_PACK_KEYS = ("shard_packs", "format_selections")
 
 
 def pack_stats() -> dict:
-    """Copy of the shard-packing build counters (for caching regressions)."""
-    return dict(_PACK_STATS)
+    """The shard-packing build counters (for caching regressions)."""
+    counters = spans.snapshot()["counters"]
+    return {k: counters.get("pack." + k, 0) for k in _PACK_KEYS}
 
 
 # ---------------------------------------------------------------------------
@@ -108,7 +111,7 @@ def plan_shard_formats(
         One ``ShardReport`` per partition, carrying the per-format
         predicted times and the per-shard best choice.
     """
-    _PACK_STATS["format_selections"] += 1
+    spans.count("pack.format_selections")
     if am is None:
         am = PM.access_model_for(m, chip)
     parts = len(bounds) - 1
@@ -246,7 +249,7 @@ def pack_shard_slabs(
     # gather ragged per-(p, q) blocks first; pad uniformly afterwards
     blocks: list[list[list[tuple[np.ndarray, np.ndarray]]]] = []
     for p in range(parts):
-        _PACK_STATS["shard_packs"] += 1
+        spans.count("pack.shard_packs")
         r0, r1 = int(bounds[p]), int(bounds[p + 1])
         row_map[p, : r1 - r0] = np.arange(r0, r1, dtype=np.int32)
         blocks.append([

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import spans
+
 Apply = Callable[[jnp.ndarray], jnp.ndarray]
 
 
@@ -135,6 +137,10 @@ def lanczos(
     "restart"`` retries the whole solve from a reseeded start vector up to
     ``max_restarts`` times (a transient fault recovers; a deterministic
     one still raises, carrying the last attempt's breakdown).
+
+    Spans (``utils.spans``): ``lanczos`` around each attempt,
+    ``lanczos.step`` around each iteration, and ``lanczos.sync`` around
+    the host's blocking reads of that iteration's alpha and beta.
     """
     if on_breakdown not in ("raise", "restart"):
         raise ValueError(f"on_breakdown={on_breakdown!r}; "
@@ -146,11 +152,12 @@ def lanczos(
     n_spmv_prior = 0
     for attempt in range(attempts):
         try:
-            result = _lanczos_once(
-                apply_A, n, m, v0, reorthogonalize,
-                # reseed each restart (and never reuse a caller v0 that
-                # already broke the recurrence once)
-                seed if attempt == 0 else seed + 7919 * attempt, dtype)
+            with spans.span("lanczos"):
+                result = _lanczos_once(
+                    apply_A, n, m, v0, reorthogonalize,
+                    # reseed each restart (and never reuse a caller v0 that
+                    # already broke the recurrence once)
+                    seed if attempt == 0 else seed + 7919 * attempt, dtype)
             result.n_spmv += n_spmv_prior
             return result
         except LanczosBreakdown as e:
@@ -171,25 +178,28 @@ def _lanczos_once(apply_A, n, m, v0, reorthogonalize, seed, dtype) -> LanczosRes
     v_prev = jnp.zeros_like(v)
     n_spmv = 0
     for j in range(m):
-        w = apply_A(v).astype(dtype)
-        n_spmv += 1
-        alpha = jnp.vdot(v, w)
-        w = w - alpha * v - beta * v_prev
-        if reorthogonalize:
-            basis = jnp.stack(V)  # (j+1, n)
-            w = w - basis.T @ (basis @ w)
-            w = w - basis.T @ (basis @ w)  # twice is enough
-        beta_new = jnp.linalg.norm(w)
-        if not (np.isfinite(float(alpha)) and np.isfinite(float(beta_new))):
-            raise LanczosBreakdown(j, float(alpha), float(beta_new))
-        alphas.append(float(alpha))
-        betas.append(float(beta_new))
-        if float(beta_new) < 1e-12 * max(1.0, abs(float(alpha))):
-            break
-        v_prev = v
-        v = w / beta_new
-        V.append(v)
-        beta = beta_new
+        with spans.span("lanczos.step"):
+            w = apply_A(v).astype(dtype)
+            n_spmv += 1
+            alpha = jnp.vdot(v, w)
+            w = w - alpha * v - beta * v_prev
+            if reorthogonalize:
+                basis = jnp.stack(V)  # (j+1, n)
+                w = w - basis.T @ (basis @ w)
+                w = w - basis.T @ (basis @ w)  # twice is enough
+            beta_new = jnp.linalg.norm(w)
+            with spans.span("lanczos.sync"):
+                a_j, b_j = float(alpha), float(beta_new)
+            if not (np.isfinite(a_j) and np.isfinite(b_j)):
+                raise LanczosBreakdown(j, a_j, b_j)
+            alphas.append(a_j)
+            betas.append(b_j)
+            if b_j < 1e-12 * max(1.0, abs(a_j)):
+                break
+            v_prev = v
+            v = w / beta_new
+            V.append(v)
+            beta = beta_new
 
     a = np.asarray(alphas)
     b = np.asarray(betas[: len(alphas) - 1])

@@ -37,12 +37,15 @@ interpreter entry, exactly as before).
 """
 from __future__ import annotations
 
+import threading
+from contextlib import contextmanager
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 
 from ..kernels import registry as R
+from ..utils import spans
 from ..utils.hw import ChipSpec, TPU_V5E
 from . import perfmodel as PM
 from .formats import (
@@ -80,14 +83,16 @@ class SpMVPlan:
     jitted executors and ``operands`` / ``operands_multi`` the device
     arrays they read (exposed so benchmarks and compile checks can
     ``.lower()`` them); ``apply`` / ``apply_multi`` call them unchecked.
+    The executors are named ``spmv_<format>_<kernel>`` and
+    ``spmm_<format>_<kernel>`` (``jit_spmv_hybrid_xla`` in a profile).
     """
 
     def __init__(self, matrix, report: PlanReport, ck_v: R.CompiledKernel,
                  ck_m: R.CompiledKernel):
         self.matrix = matrix
         self.report = report
-        self.kernel = jax.jit(ck_v.kernel)
-        self.kernel_multi = jax.jit(ck_m.kernel)
+        self.kernel = jax.jit(_named(ck_v.kernel, "spmv", report.format, ck_v.label))
+        self.kernel_multi = jax.jit(_named(ck_m.kernel, "spmm", report.format, ck_m.label))
         self.operands = ck_v.operands
         self.operands_multi = ck_m.operands
 
@@ -211,10 +216,7 @@ class SpMVPlan:
         key = (fmt, backend, cfg.chunk_block, cfg.width_block, chip.name,
                am.value_bytes, am.index_bytes,
                getattr(tuning, "token", None))
-        cache = getattr(matrix, "_spmv_plans", None)
-        if cache is None:
-            cache = {}
-            object.__setattr__(matrix, "_spmv_plans", cache)
+        cache = _memo(matrix, "_spmv_plans")
         plan = cache.get(key)
         if plan is None:
             plan = _compile(matrix, fmt, chip, am, backend, cfg.chunk_block,
@@ -253,9 +255,17 @@ def resolve_format(matrix, format: str, *, chip: ChipSpec = TPU_V5E,
     if format == "auto":
         if fmt not in ("csr", "coo"):
             return matrix
-        choice = PM.select_format(_as_csr_container(matrix), am=am, chip=chip,
-                                  backend=_resolve_backend(backend),
-                                  tuning=tuning, **select_kw)
+        be = _resolve_backend(backend)
+        key = (chip, am, be, getattr(tuning, "token", None),
+               tuple(sorted(select_kw.items())))
+        memo = _memo(matrix, "_format_choices")
+        choice = memo.get(key)
+        if choice is None:
+            src = _as_csr_container(matrix)
+            with _plan_span("plan.select"):
+                choice = PM.select_format(src, am=am, chip=chip, backend=be,
+                                          tuning=tuning, **select_kw)
+            memo[key] = choice
         return _convert_cached(matrix, choice.format, choice.convert_kwargs)
     if format == fmt:
         return matrix
@@ -273,19 +283,26 @@ def _as_csr_container(matrix):
     return _convert_cached(matrix, "csr", {})
 
 
-def _convert_cached(matrix, fmt: str, kw: dict, value_dtype: str | None = None):
-    from .formats import COO, CSR, convert, with_value_dtype
-    cache = getattr(matrix, "_fmt_cache", None)
+def _memo(matrix, attr: str) -> dict:
+    """A dict pinned on the (frozen) container under ``attr``."""
+    cache = getattr(matrix, attr, None)
     if cache is None:
         cache = {}
-        object.__setattr__(matrix, "_fmt_cache", cache)
+        object.__setattr__(matrix, attr, cache)
+    return cache
+
+
+def _convert_cached(matrix, fmt: str, kw: dict, value_dtype: str | None = None):
+    from .formats import COO, CSR, convert, with_value_dtype
+    cache = _memo(matrix, "_fmt_cache")
     key = (fmt, value_dtype, tuple(sorted(kw.items())))
     obj = cache.get(key)
     if obj is None:
-        src = CSR.from_coo(matrix) if isinstance(matrix, COO) else matrix
-        obj = src if fmt == "csr" else convert(src, fmt, **kw)
-        if value_dtype is not None:
-            obj = with_value_dtype(obj, value_dtype)
+        with _plan_span("plan.convert"):
+            src = CSR.from_coo(matrix) if isinstance(matrix, COO) else matrix
+            obj = src if fmt == "csr" else convert(src, fmt, **kw)
+            if value_dtype is not None:
+                obj = with_value_dtype(obj, value_dtype)
         if obj is not src:
             # back-reference for the tuning DB: a converted container is
             # signed through its source CSR's pattern (tunedb.signature_of)
@@ -300,6 +317,35 @@ def _convert_cached(matrix, fmt: str, kw: dict, value_dtype: str | None = None):
 # ---------------------------------------------------------------------------
 # compilation internals
 # ---------------------------------------------------------------------------
+
+
+_PLAN_SPAN = threading.local()
+
+
+@contextmanager
+def _plan_span(name: str):
+    """``spans.span(name)`` unless another plan span is open on this thread:
+    the outermost owns the time, so ``plan.select``, ``plan.convert`` and
+    ``plan.build`` never nest (a tuning DB's freshness check converts its
+    candidates inside ``plan.select``)."""
+    if getattr(_PLAN_SPAN, "open", False):
+        yield
+        return
+    _PLAN_SPAN.open = True
+    try:
+        with spans.span(name):
+            yield
+    finally:
+        _PLAN_SPAN.open = False
+
+
+def _named(kernel, op: str, fmt: str, label: str):
+    """``kernel`` under the function name ``<op>_<fmt>_<label>``, which
+    ``jax.jit`` gives the executor's program (``-`` becomes ``_``)."""
+    def executor(operands, x):
+        return kernel(operands, x)
+    executor.__name__ = executor.__qualname__ = f"{op}_{fmt}_{label}".replace("-", "_")
+    return executor
 
 
 def _resolve_backend(backend: str) -> str:
@@ -355,7 +401,8 @@ def _pick_entry(matrix, fmt: str, op: str, backend: str,
     whose tiling cannot fit VMEM, compiles the XLA path).
     """
     if backend == "auto":
-        be, _ = R.select_backend(matrix, fmt, op, ctx)
+        with _plan_span("plan.select"):
+            be, _ = R.select_backend(matrix, fmt, op, ctx)
         return be
     if R.has(fmt, op, backend) and R.get(fmt, op, backend).probe(matrix, ctx).ok:
         return backend
@@ -375,8 +422,9 @@ def _compile(matrix, fmt, chip, am, backend, chunk_block, width_block,
     be_mm = "xla" if (backend == "pallas" and be == "pallas_interpret") else be
     be_v = _pick_entry(matrix, fmt, "spmv", be, ctx)
     be_m = _pick_entry(matrix, fmt, "spmm", be_mm, ctx)
-    ck_v = R.build(matrix, fmt, "spmv", be_v, ctx)
-    ck_m = R.build(matrix, fmt, "spmm", be_m, ctx)
+    with _plan_span("plan.build"):
+        ck_v = R.build(matrix, fmt, "spmv", be_v, ctx)
+        ck_m = R.build(matrix, fmt, "spmm", be_m, ctx)
     choice = ck_v.choice if isinstance(ck_v.choice, PM.BlockChoice) else None
     return SpMVPlan(matrix, _report(matrix, fmt, chip, am, ck_v.label, choice),
                     ck_v, ck_m)

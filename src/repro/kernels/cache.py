@@ -3,29 +3,32 @@
 Host-derived metadata (CSR row ids, JDS segment tables, SELL padded views,
 DIA shift-gather tables, row-split slabs) is computed **once per container**
 and pinned on the (frozen) dataclass via ``object.__setattr__`` — repeated
-SpMV calls on the same matrix never redo preprocessing.  ``precompute_stats``
-exposes the build counters so tests can assert no recomputation (the plan
-layer's contract).
+SpMV calls on the same matrix never redo preprocessing.  Each build counts
+one under ``precompute.<kind>`` in ``utils.spans``' counter table;
+``precompute_stats`` is the view of those counters that tests read to
+assert no recomputation (the plan layer's contract).
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
 
-#: build counters per precompute kind, for regression tests ("preprocessing
-#: happens once per matrix").  Kernel modules add their own keys at import.
-_PRECOMPUTE_STATS: dict[str, int] = {}
+from ..utils import spans
+
+_STAT = "precompute."   # prefix of the build counters in utils.spans
 
 
 def register_stat(name: str) -> str:
-    """Declare a build counter (idempotent); returns the name for reuse."""
-    _PRECOMPUTE_STATS.setdefault(name, 0)
+    """Declare a build counter (idempotent); returns the name for reuse.
+    Kernel modules declare their kinds at import."""
+    spans.count(_STAT + name, 0)
     return name
 
 
 def precompute_stats() -> dict:
-    """Copy of the host-preprocessing build counters."""
-    return dict(_PRECOMPUTE_STATS)
+    """``{kind: builds}`` of the host-preprocessing build counters."""
+    return {k[len(_STAT):]: n for k, n in spans.snapshot()["counters"].items()
+            if k.startswith(_STAT)}
 
 
 def cached(m, attr: str, stat: str, build):
@@ -39,7 +42,7 @@ def cached(m, attr: str, stat: str, build):
     """
     out = getattr(m, attr, None)
     if out is None:
-        _PRECOMPUTE_STATS[stat] = _PRECOMPUTE_STATS.get(stat, 0) + 1
+        spans.count(_STAT + stat)
         out = build()
         object.__setattr__(m, attr, out)
     return out

@@ -12,6 +12,7 @@ not lower there.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from ..core.formats import HybridDIA
@@ -27,10 +28,16 @@ def hybrid_spmv_loop(m: HybridDIA, x):
 
 def _sum_of(ck_d: CompiledKernel, ck_s: CompiledKernel, label: str,
             choice=None) -> CompiledKernel:
-    """The DIA and SELL parts' executors summed; each keeps its operands."""
-    return CompiledKernel(
-        lambda ops, x: ck_d.kernel(ops[0], x) + ck_s.kernel(ops[1], x),
-        label, choice, (ck_d.operands, ck_s.operands))
+    """The DIA and SELL parts' executors summed; each keeps its operands.
+    The parts trace under the name scopes ``dia`` and ``sell``, which name
+    their operations in the program's metadata and change no code."""
+    def kernel(ops, x):
+        with jax.named_scope("dia"):
+            y_dia = ck_d.kernel(ops[0], x)
+        with jax.named_scope("sell"):
+            y_sell = ck_s.kernel(ops[1], x)
+        return y_dia + y_sell
+    return CompiledKernel(kernel, label, choice, (ck_d.operands, ck_s.operands))
 
 
 # --- registry entries -------------------------------------------------------
