@@ -6,7 +6,7 @@ same weights for every decoded token.  ``SpMVPlan.compile`` turns a one-shot
 format container into a reusable executor:
 
 1. **Cached preprocessing** — all host-derived metadata (CSR row-ids, SELL
-   padded ``(nc, W, C)`` views, JDS segment tables, DIA shift-gather tables)
+   padded ``(nc, W, C)`` views, JDS segment tables, DIA shifted-slice geometry)
    is computed exactly once per matrix and pinned on the container
    (``core.spmv`` build-once caches), then device-put once.
 2. **Vectorized kernels** — every format executes as O(1) traced ops

@@ -32,7 +32,6 @@ from ..kernels.csr import (  # noqa: F401
     csr_spmv_searchsorted,
 )
 from ..kernels.dia import (  # noqa: F401
-    dia_gather_tables,
     dia_spmm,
     dia_spmv,
     dia_spmv_loop,

@@ -1,7 +1,7 @@
 """Build-once host-preprocessing cache shared by every kernel module.
 
 Host-derived metadata (CSR row ids, JDS segment tables, SELL padded views,
-DIA shift-gather tables, row-split slabs) is computed **once per container**
+DIA Pallas padding, row-split slabs) is computed **once per container**
 and pinned on the (frozen) dataclass via ``object.__setattr__`` — repeated
 SpMV calls on the same matrix never redo preprocessing.  Each build counts
 one under ``precompute.<kind>`` in ``utils.spans``' counter table;

@@ -3,6 +3,13 @@
 Registry entries: ``(dia, {spmv, spmm}, {xla, loop_reference})`` plus the
 Pallas SpMV (``dia_spmv.py``'s shifted-window kernel) under
 ``{pallas, pallas_interpret}``.
+
+The XLA entries stream each stored diagonal against a shifted slice of the
+zero-padded input (``y = Σ_k data[k] * x[i + offsets[k]]``), one diagonal
+per step of a loop: each slice starts at ``pad0 + offsets[k]``, a
+per-container constant, so the program reads ``data`` and ``x`` with
+stride-1 dynamic slices, gathers nothing, and does not grow with the
+number of diagonals.
 """
 from __future__ import annotations
 
@@ -11,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.formats import DIA
-from ..utils import hw
+from ..utils import hw, spans
 from . import dia_spmv as KP
 from .accum import acc_dtype
 from .cache import cached, register_stat, spmm_by_columns, to_device
@@ -25,66 +32,69 @@ from .registry import (
     register_kernel,
 )
 
-register_stat("dia_gather_tables")
 register_stat("dia_pallas_prepare")
 
 
-def dia_gather_tables(m: DIA):
-    """Padded shift-gather tables: idx[k, i] = i + offsets[k] clipped into
-    range, data masked to zero where the shift runs off the matrix.  One
-    (nd, n) gather then replaces the per-diagonal dynamic_slice chain."""
-
-    def build():
+def shifted_slices(m: DIA) -> tuple:
+    """The geometry of the XLA executors, once per container: ``(pad0,
+    pad1, n, table)`` — the zeros padded before and after ``x`` so that
+    every slice ``[pad0 + off, pad0 + off + n)`` of a diagonal that meets
+    the matrix lies inside it, the row count, and the int32 ``(2, nd)``
+    table of those diagonals' indices and slice starts.  A container with
+    a diagonal to stream counts once under ``dia.shifted_slices``."""
+    geo = getattr(m, "_shifted_slices", None)
+    if geo is None:
         n, ncols = m.shape
-        offs = np.asarray(m.offsets, dtype=np.int64)
-        i = np.arange(n, dtype=np.int64)
-        idx = i[None, :] + offs[:, None]                      # (nd, n)
-        valid = (idx >= 0) & (idx < ncols)
-        idx = np.clip(idx, 0, max(0, ncols - 1))
-        # np.where, not * valid: bool multiply is undefined for ml_dtypes fp8
-        d = np.asarray(m.data)[:, :n]
-        data = np.where(valid, d, np.zeros((), dtype=d.dtype))
-        return idx.astype(np.int32), data
-
-    return cached(m, "_gather_tables", "dia_gather_tables", build)
+        offs = [int(o) for o in np.asarray(m.offsets)]
+        diags = [k for k, o in enumerate(offs) if -n < o < ncols]
+        kept = [offs[k] for k in diags]
+        pad0 = max([0] + [-o for o in kept])
+        pad1 = max([0] + [o + n - ncols for o in kept])
+        table = np.asarray([diags, [pad0 + o for o in kept]], np.int32).reshape(2, -1)
+        geo = (pad0, pad1, n, table)
+        object.__setattr__(m, "_shifted_slices", geo)
+        if diags:
+            spans.count("dia.shifted_slices")
+    return geo
 
 
 def _operands(m: DIA) -> tuple:
-    idx, data = dia_gather_tables(m)
-    return idx, data, m.scale
+    return m.data, m.scale, shifted_slices(m)[-1]
 
 
-def dia_spmv_arrays(ops, x: jnp.ndarray) -> jnp.ndarray:
-    """Vectorized DIA: one shift-gather of shape (nd, n), one reduction.
-    Quantized containers carry a per-diagonal fp32 scale, applied to the
-    (nd, n) product table before the reduction over diagonals."""
-    idx, data, scale = ops
-    if data.shape[0] == 0:
-        return jnp.zeros(data.shape[1], dtype=x.dtype)
+def dia_shifted_arrays(geo: tuple, ops, x: jnp.ndarray) -> jnp.ndarray:
+    """``y[i] = Σ_k data[k, i] * x[i + offsets[k]]`` for ``x`` of shape
+    ``(ncols,)`` or a ``(ncols, K)`` block: a loop over the table's
+    diagonals, each against a shifted slice (along axis 0) of ``x`` padded
+    with zeros.  Slots of a diagonal that run off the matrix meet the
+    padding, so they add nothing.  Quantized containers carry a
+    per-diagonal fp32 scale, applied to each diagonal before its product."""
+    pad0, pad1, n, _ = geo
+    data, scale, table = ops
+    shape = (n,) + x.shape[1:]
+    if table.shape[1] == 0:
+        return jnp.zeros(shape, dtype=x.dtype)
     acc = acc_dtype(data.dtype, x.dtype)
-    prod = jnp.asarray(data).astype(acc) * jnp.take(x, idx, axis=0).astype(acc)
-    if scale is not None:
-        prod = prod * jnp.asarray(scale).astype(acc)[:, None]
-    return jnp.sum(prod, axis=0)
+    table = jnp.asarray(table)
+    xp = jnp.pad(x.astype(acc), [(pad0, pad1)] + [(0, 0)] * (x.ndim - 1))
 
+    def step(j, y):
+        k = table[0, j]
+        d = jax.lax.dynamic_index_in_dim(data, k, keepdims=False)[:n].astype(acc)
+        if scale is not None:
+            d = d * jax.lax.dynamic_index_in_dim(scale, k, keepdims=False).astype(acc)
+        xs = jax.lax.dynamic_slice_in_dim(xp, table[1, j], n, axis=0)
+        return y + d.reshape((n,) + (1,) * (x.ndim - 1)) * xs
 
-def dia_spmm_arrays(ops, X: jnp.ndarray) -> jnp.ndarray:
-    idx, data, scale = ops
-    if data.shape[0] == 0:
-        return jnp.zeros((data.shape[1], X.shape[1]), dtype=X.dtype)
-    acc = acc_dtype(data.dtype, X.dtype)
-    d = jnp.asarray(data).astype(acc)
-    if scale is not None:
-        d = d * jnp.asarray(scale).astype(acc)[:, None]
-    return jnp.einsum("kn,knj->nj", d, jnp.take(X, idx, axis=0).astype(acc))
+    return jax.lax.fori_loop(0, table.shape[1], step, jnp.zeros(shape, acc))
 
 
 def dia_spmv(m: DIA, x: jnp.ndarray) -> jnp.ndarray:
-    return dia_spmv_arrays(_operands(m), x)
+    """``m @ x`` for a vector or an ``(ncols, K)`` block (``dia_spmm``)."""
+    return dia_shifted_arrays(shifted_slices(m), _operands(m), x)
 
 
-def dia_spmm(m: DIA, X: jnp.ndarray) -> jnp.ndarray:
-    return dia_spmm_arrays(_operands(m), X)
+dia_spmm = dia_spmv
 
 
 def dia_spmv_loop(m: DIA, x: jnp.ndarray) -> jnp.ndarray:
@@ -119,17 +129,17 @@ def dia_prepared(m: DIA, tile: int = KP.TILE_QUANTUM):
 
 
 @register_kernel("dia", "spmv", "xla",
-                 description="one (nd, n) shift-gather + reduction")
+                 description="shifted slices of a zero-padded x, no gather")
 def _build_spmv(m: DIA, ctx) -> CompiledKernel:
-    return CompiledKernel(dia_spmv_arrays, "xla",
+    geo = shifted_slices(m)
+    return CompiledKernel(lambda ops, x: dia_shifted_arrays(geo, ops, x), "xla",
                           operands=to_device(m, *_operands(m)))
 
 
 @register_kernel("dia", "spmm", "xla",
-                 description="multi-vector shift-gather einsum")
+                 description="shifted row slices of a zero-padded block")
 def _build_spmm(m: DIA, ctx) -> CompiledKernel:
-    return CompiledKernel(dia_spmm_arrays, "xla",
-                          operands=to_device(m, *_operands(m)))
+    return _build_spmv(m, ctx)   # the same slices, along axis 0 of the block
 
 
 @register_kernel("dia", "spmv", "loop_reference", auto=False,

@@ -44,7 +44,7 @@ def _sum_of(ck_d: CompiledKernel, ck_s: CompiledKernel, label: str,
 
 
 @register_kernel("hybrid", "spmv", "xla",
-                 description="DIA shift-gather + SELL (padded or flat) sum")
+                 description="DIA shifted slices + SELL (padded or flat) sum")
 def _build_spmv(m: HybridDIA, ctx) -> CompiledKernel:
     return _sum_of(KD._build_spmv(m.dia, ctx), KS._build_spmv(m.rest, ctx),
                    "xla")

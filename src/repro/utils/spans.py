@@ -22,6 +22,8 @@ Names in use (dots separate a layer from its part):
 - counters ``precompute.<kind>`` (``kernels.cache``) and
   ``pack.shard_packs``, ``pack.format_selections``
   (``core.distributed_plan``): host preprocessing builds.
+- counter ``dia.shifted_slices`` (``kernels.dia``): DIA containers whose
+  diagonals the XLA executors stream as shifted slices of x, once each.
 """
 from __future__ import annotations
 

@@ -1,4 +1,5 @@
 """Pallas kernels (interpret mode) vs ref.py oracles: shape/dtype sweeps."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -114,6 +115,66 @@ def test_dia_negative_offsets():
         y = np.asarray(dia_spmv(dia, x, tile=tile, interpret=True))
         np.testing.assert_allclose(y, y_ref, rtol=1e-4, atol=1e-4,
                                    err_msg=f"tile={tile}")
+
+
+def _dia(shape, offsets, seed=0):
+    """A DIA container with random values, zero where a diagonal runs off
+    the matrix (the format's contract)."""
+    n, ncols = shape
+    data = np.random.default_rng(seed).standard_normal((len(offsets), n)).astype(np.float32)
+    i = np.arange(n)
+    for k, off in enumerate(offsets):
+        data[k, (i + off < 0) | (i + off >= ncols)] = 0.0
+    return F.DIA(np.asarray(offsets, np.int32), data, shape)
+
+
+@pytest.mark.parametrize("shape,offsets,value_dtype", [
+    ((300, 300), [-40, -7, -1], None),               # negative only
+    ((300, 300), [0, 3, 128, 299], None),            # positive only
+    ((300, 200), [-5, 0, 17, 200, 260], None),       # offsets at and past ncols
+    ((200, 300), [-250, -200, -3, 0, 129, 299], None),  # wide; wholly outside
+    ((300, 200), [-299, -1, 0, 5], None),            # tall
+    ((300, 300), [], None),                          # empty
+    ((300, 300), [-9, 0, 4, 130], "int8"),           # per-diagonal scale
+    ((300, 280), list(range(-150, 290, 4)), None),   # 110 diagonals
+    ((300, 300), list(range(-200, 200, 5)), "int8"),  # 80 diagonals, scaled
+])
+def test_dia_xla_shifted_slices_match_dense(shape, offsets, value_dtype):
+    """The XLA DIA executors (shifted slices of a zero-padded x)
+    against the dense product, SpMV and SpMM, jitted as a plan runs them."""
+    from repro.kernels import registry as REG
+    m = _dia(shape, offsets)
+    if value_dtype is not None:
+        m = F.with_value_dtype(m, value_dtype)
+        assert m.scale is not None
+    dense = np.asarray(F.dequantize(m).to_dense(), np.float64)
+    rng = np.random.default_rng(1)
+    x = rng.standard_normal(shape[1]).astype(np.float32)
+    X = rng.standard_normal((shape[1], 3)).astype(np.float32)
+    ck = REG.build(m, "dia", "spmv", "xla")
+    ckm = REG.build(m, "dia", "spmm", "xla")
+    # the stored values (and scales) are the only operands of the values'
+    # size: no (nd, n) index table
+    for ops in (ck.operands, ckm.operands):
+        leaves = jax.tree.leaves(ops)
+        assert leaves[0].shape == m.data.shape
+        assert all(a.size <= 2 * len(offsets) for a in leaves[1:])
+    y = np.asarray(jax.jit(ck.kernel)(ck.operands, x))
+    Y = np.asarray(jax.jit(ckm.kernel)(ckm.operands, X))
+    assert y.shape == (shape[0],) and Y.shape == (shape[0], 3)
+    np.testing.assert_allclose(y, dense @ x, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(Y, dense @ X, rtol=1e-5, atol=1e-5)
+
+
+def test_hybrid_xla_plan_matches_its_loop_reference(hh_small):
+    from repro.core.plan import SpMVPlan
+    from repro.core.planconfig import PlanConfig
+    from repro.kernels import registry as REG
+    p = SpMVPlan.compile(hh_small, PlanConfig(format="hybrid", backend="xla"))
+    assert p.report.format == "hybrid" and p.report.kernel == "xla"
+    x = jnp.asarray(np.random.default_rng(3).standard_normal(hh_small.shape[1]).astype(np.float32))
+    ref = REG.build(p.matrix, "hybrid", "spmv", "loop_reference").fn(x)
+    np.testing.assert_allclose(np.asarray(p(x)), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
 # --- grouped GEMM ----------------------------------------------------------

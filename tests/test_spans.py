@@ -180,6 +180,18 @@ def test_the_hybrid_executors_are_named_and_their_parts_scoped(hh):
     np.testing.assert_allclose(np.asarray(p(x)), np.asarray(p.apply(x)))
 
 
+def test_dia_shifted_slices_counts_each_dia_xla_build():
+    def count():
+        return spans.snapshot()["counters"].get("dia.shifted_slices", 0)
+    # a matrix of its own: the count is once per converted container
+    hh = holstein_hubbard_surrogate(1_500, seed=5, dtype=np.float32)
+    n0 = count()
+    p = SpMVPlan.compile(hh, PlanConfig(format="hybrid", backend="xla"))
+    assert len(p.matrix.dia.offsets) and count() == n0 + 1  # SpMV and SpMM share it
+    SpMVPlan.compile(hh, PlanConfig(format="sell", backend="xla"))
+    assert count() == n0 + 1
+
+
 def test_executor_names_map_dashes():
     f = P._named(lambda ops, x: x, "spmv", "sell", "pallas-interpret")
     assert f.__name__ == "spmv_sell_pallas_interpret"

@@ -9,6 +9,8 @@ chip, which refuses what Mosaic or XLA:TPU cannot lower.  One test fakes
 the TPU platform to check that the probes refuse the kernels that do not
 lower.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -77,6 +79,20 @@ def test_dia_pallas_compiles_for_v5e(one_chip):
     ck = KD._build_dia_pallas(dia, R.KernelContext(), interpret=False)
     c = _compile(ck, (N_PAPER,), one_chip)
     assert "tpu_custom_call" in c.as_text()
+
+
+def test_dia_xla_executor_streams_without_a_gather(one_chip):
+    """The DIA part of the phase-(a) hybrid on XLA at N = 1,201,200: shifted
+    slices of a padded x, so the chip's program gathers nothing and
+    its operands carry no (nd, n) index table."""
+    from repro.kernels import dia as KD
+    dia = _hh_dia()
+    ck = KD._build_spmv(dia, R.KernelContext())
+    nd = len(dia.offsets)
+    assert not any(jnp.issubdtype(a.dtype, jnp.integer) and a.shape == (nd, N_PAPER)
+                   for a in jax.tree.leaves(ck.operands))
+    hlo = _compile(ck, (N_PAPER,), one_chip).as_text()
+    assert not re.search(r"\bgather\(", hlo)
 
 
 @pytest.mark.parametrize("k", [None, 8])
