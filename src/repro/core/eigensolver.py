@@ -5,13 +5,15 @@ a vector as the dominant operation ... the fraction spent in the sparse
 matrix-vector multiplication may easily constitute over 99 % of total run
 time" (Sec. 1).  This module supplies that surrounding algorithm so the
 SpMV formats plug into a real solver: plain Lanczos with optional full
-reorthogonalization, plus a spectral-extent estimator used by tests.
+reorthogonalization, plus a spectral-extent estimator used by tests, and
+PageRank by power iteration with the GAP benchmark suite's semantics.
 
 The SpMV is injected as a closure, so any format / kernel / distribution
 strategy (including the shard_map distributed SpMV) drops in unchanged.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable
 
@@ -240,3 +242,56 @@ def power_iteration(apply_A: Apply, n: int, iters: int = 200, seed: int = 0,
     v = jax.lax.fori_loop(0, iters, body, v)
     w = apply_A(v)
     return float(jnp.vdot(v, w))
+
+
+@dataclass
+class PageRankResult:
+    scores: jnp.ndarray          # the last iterate, on the device
+    n_iterations: int
+    n_spmv: int
+    l1_changes: np.ndarray       # ||x_k - x_{k-1}||_1 per iteration
+
+
+@functools.partial(jax.jit, static_argnames="damping")
+def _pagerank_update(teleport, x, y, damping):
+    x_new = (1.0 - damping) * teleport + damping * y
+    return x_new, jnp.sum(jnp.abs(x_new - x))
+
+
+def pagerank(apply_P: Apply, n: int, teleport, damping: float = 0.85,
+             tol: float = 1e-4, max_iters: int = 20,
+             dtype=jnp.float32) -> PageRankResult:
+    """PageRank by power iteration, as the GAP benchmark suite runs it.
+
+    ``apply_P`` is the column-stochastic transition ``P = A D^-1`` (any
+    ``Apply``: a callable, a plan, a bare container or a distributed plan,
+    through :func:`as_apply`); ``teleport`` the length-``n`` teleport
+    distribution (GAP's is uniform, ``1/n``).  Starting from ``teleport``,
+    each iteration is exactly one SpMV ``y = P x`` and one jitted update
+    ``x' = (1 - damping) * teleport + damping * y`` that also returns
+    ``||x' - x||_1``; the host reads that change once per iteration and
+    stops when it falls below ``tol`` (GAP's test), or after
+    ``max_iters``.  Vertices without edges leak their mass, as in GAP: no
+    dangling redistribution.
+
+    Spans (``utils.spans``): ``pagerank`` around the solve,
+    ``pagerank.step`` around each iteration, and ``pagerank.sync`` around
+    the host's blocking read of that iteration's L1 change.
+    """
+    apply_P = as_apply(apply_P)
+    t = jnp.asarray(teleport, dtype)
+    if t.shape != (n,):
+        raise ValueError(f"teleport has shape {t.shape}, expected ({n},)")
+    x = t
+    changes = []
+    with spans.span("pagerank"):
+        for _ in range(max_iters):
+            with spans.span("pagerank.step"):
+                y = apply_P(x).astype(dtype)
+                x, err = _pagerank_update(t, x, y, damping=float(damping))
+                with spans.span("pagerank.sync"):
+                    changes.append(float(err))
+            if changes[-1] < tol:
+                break
+    return PageRankResult(scores=x, n_iterations=len(changes), n_spmv=len(changes),
+                          l1_changes=np.asarray(changes))

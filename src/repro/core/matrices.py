@@ -16,7 +16,9 @@ Two generators for the paper's physics matrix:
    reproducing the Fig-5 statistics at any N: ~14 nnz/row, ~60 % of nnz in
    12 dense secondary diagonals, remainder scattered over a band, symmetric.
 
-Plus generic pattern generators used by tests and microbenchmarks.
+Plus the Graph500 Kronecker graph and its PageRank transition matrix
+(``kronecker_graph``, ``pagerank_transition``), and generic pattern
+generators used by tests and microbenchmarks.
 """
 from __future__ import annotations
 
@@ -228,6 +230,68 @@ def holstein_hubbard_surrogate(
     rows_u = (uniq // n).astype(np.int32)
     cols_u = (uniq % n).astype(np.int32)
     return CSR.from_coo(COO(rows_u, cols_u, vsum.astype(dtype), (n, n)))
+
+
+# ---------------------------------------------------------------------------
+# Graph500 Kronecker graph (the GAP benchmark suite's ``kron`` input)
+# ---------------------------------------------------------------------------
+
+
+def kronecker_graph(scale: int, edge_factor: int = 16,
+                    initiator: tuple = (0.57, 0.19, 0.19), seed: int = 0) -> CSR:
+    """The Graph500 Kronecker graph on ``2**scale`` vertices, as the
+    undirected adjacency matrix GAP builds from it (values 1.0).
+
+    Follows the Graph500 reference ``kronecker_generator.m``: each of the
+    ``edge_factor * 2**scale`` edges takes one quadrant draw per bit of
+    its endpoints (row bit set with probability ``1 - (A + B)``, then the
+    column bit with ``C / (1 - (A + B))`` or ``A / (A + B)`` conditioned
+    on it; ``D`` is the rest), then the vertex labels are permuted at
+    random.  The reference's shuffle of the edge order is left out: the
+    graph is the same set of edges.  Then, as GAP builds an undirected
+    graph: symmetrise, drop self-loops and duplicate edges.  Vectorised
+    over the edges; the only Python loop runs over the ``scale`` bits.
+    """
+    a, b, c = initiator
+    n = 1 << scale
+    m = edge_factor * n
+    rng = np.random.default_rng(seed)
+    ab = a + b
+    c_norm = c / (1.0 - ab)
+    a_norm = a / ab
+    ii = np.zeros(m, np.int64)
+    jj = np.zeros(m, np.int64)
+    u = np.empty(m)                     # work buffers, reused for every bit
+    ii_bit, lo_bit, hi_bit = (np.empty(m, bool) for _ in range(3))
+    shifted = np.empty(m, np.int64)
+    for bit in range(scale):
+        np.greater(rng.random(out=u), ab, out=ii_bit)
+        rng.random(out=u)
+        np.greater(u, a_norm, out=lo_bit)               # the column bit where ii_bit is 0
+        np.greater(u, c_norm, out=hi_bit)               # ... and where it is 1
+        jj_bit = np.where(ii_bit, hi_bit, lo_bit)
+        ii |= np.left_shift(ii_bit, bit, out=shifted, dtype=np.int64)
+        jj |= np.left_shift(jj_bit, bit, out=shifted, dtype=np.int64)
+    perm = rng.permutation(n)
+    ii, jj = perm[ii], perm[jj]
+    keep = ii != jj
+    ii, jj = ii[keep], jj[keep]
+    # both directions of every edge, deduplicated and in row-major order
+    rows, cols = np.divmod(np.unique(np.concatenate([ii * n + jj, jj * n + ii])), n)
+    row_ptr = np.zeros(n + 1, np.int32)
+    np.cumsum(np.bincount(rows, minlength=n), out=row_ptr[1:])
+    return CSR(row_ptr, cols.astype(np.int32), np.ones(len(cols), np.float32), (n, n))
+
+
+def pagerank_transition(adj: CSR, dtype=np.float32) -> CSR:
+    """The column-stochastic PageRank operator ``P = A D^-1`` of an
+    adjacency ``adj``: the value at ``(i, j)`` is ``1 / deg(j)``, with
+    ``deg(j)`` the stored entries of column ``j``.  Columns of vertices
+    without edges are empty (their mass leaks, as in GAP)."""
+    col = np.asarray(adj.col_idx)
+    deg = np.bincount(col, minlength=adj.shape[1]).astype(np.float64)
+    val = (1.0 / deg[col]).astype(dtype)
+    return CSR(np.asarray(adj.row_ptr).copy(), col.copy(), val, tuple(adj.shape))
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,9 @@ Names in use (dots separate a layer from its part):
 - ``lanczos``, ``lanczos.step``, ``lanczos.sync`` (``core.eigensolver``):
   one solve attempt, one iteration, and the host's blocking reads of the
   recurrence coefficients.
+- ``pagerank``, ``pagerank.step``, ``pagerank.sync`` (``core.eigensolver``):
+  one PageRank solve, one iteration, and the host's blocking read of the
+  iteration's L1 change.
 - ``plan.select``, ``plan.convert``, ``plan.build`` (``core.plan``): format
   and backend selection, format conversion, executor builds with their
   tables and device copies.  They never nest in one another.

@@ -20,11 +20,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from repro.core import formats as F
 from repro.core import perfmodel as PM
-from repro.core.matrices import holstein_hubbard_surrogate, laplacian_3d
+from repro.core.matrices import (holstein_hubbard_surrogate, kronecker_graph,
+                                 laplacian_3d, pagerank_transition)
 from repro.kernels import registry as R
 
 N_PAPER = 1_201_200   # chip_smoke.py phase (a)
 GRID = 104            # chip_smoke.py phase (b)
+N_KRON = 1 << 20      # the graph500_pagerank cell: a scale-20 Kronecker graph
+KRON_ELEMS = 32_200_000   # its SELL pack's flat elements (nnz ~31.4M, x1.01 padding)
 
 
 @pytest.fixture(scope="module")
@@ -125,6 +128,40 @@ def test_paper_plan_executor_does_not_embed_the_matrix(one_chip, monkeypatch):
     ma = _compile(ck, (N_PAPER,), one_chip).memory_analysis()
     assert ma.generated_code_size_in_bytes < 16 * 1024**2
     assert ma.argument_size_in_bytes > 100 * 1024**2  # the matrix is an argument
+
+
+def test_pagerank_sell_flat_spmv_and_update_compile_for_v5e(one_chip, monkeypatch):
+    """The graph500_pagerank cell's programs at its full size: the SELL
+    XLA flat SpMV (a gather and a segment-sum over ~32.2M elements, the
+    inverse-permutation gather over 2^20 rows) and the PageRank update.
+    A Kronecker graph's longest row is thousands of times its mean, so the
+    plan's operands must hold no padded (nc, W_max, C) view."""
+    import functools
+
+    from repro.core.eigensolver import _pagerank_update
+    from repro.kernels import sell as KS
+    monkeypatch.setattr(PM, "sell_flat_overhead",
+                        lambda family=None: PM.SELL_FLAT_OVERHEAD["tpu"])
+    P = pagerank_transition(kronecker_graph(12, seed=0))
+    choice = PM.select_format(P)
+    assert choice.format == "sell"
+    s = F.convert(P, "sell", **choice.convert_kwargs)
+    assert PM.sell_xla_uses_flat(s)
+    ck = R.build(s, "sell", "spmv", "xla")
+    leaves = jax.tree.leaves(ck.operands)
+    assert [a.ndim for a in leaves] == [1, 1, 1, 1]     # col, val, row ids, inverse perm
+    elems, n = len(s.val), P.shape[0]
+    assert [a.shape[0] for a in leaves] == [elems, elems, elems, n]
+
+    full = [jax.ShapeDtypeStruct((KRON_ELEMS if a.shape[0] == elems else N_KRON,), a.dtype,
+                                 sharding=one_chip) for a in leaves]
+    ops = jax.tree.unflatten(jax.tree.structure(ck.operands), full)
+    kernel = functools.partial(KS._flat_spmv_kernel, n_rows=N_KRON, n_segments=N_KRON, C=s.C)
+    x = jax.ShapeDtypeStruct((N_KRON,), jnp.float32, sharding=one_chip)
+    ma = jax.jit(kernel).lower(ops, x).compile().memory_analysis()
+    assert ma.argument_size_in_bytes >= 3 * 4 * KRON_ELEMS    # the matrix is an argument
+    update = _pagerank_update.lower(x, x, x, damping=0.85).compile()
+    assert update.memory_analysis().output_size_in_bytes >= 4 * N_KRON
 
 
 def test_overlap_executor_compiles_on_2x2(topo):
